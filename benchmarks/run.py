@@ -411,11 +411,12 @@ if kind == "pipeline_compressed_a2a":
     # the deterministic compressed-vs-raw bytes-moved ratio.
     import jax, jax.numpy as jnp
     from repro.core import grad_compress as gc
+    from repro.launch.mesh import make_mesh
     from repro.pipeline_par import pipeline_apply
 
     n_stages, n_micro, d = 4, 8, 512
     mb = max(n // (n_micro * d * 8), 1)           # scale batch with SZX_BENCH_N
-    mesh = jax.make_mesh((n_stages,), ("stage",))
+    mesh = make_mesh((n_stages,), ("stage",))
     rng = np.random.default_rng(0)
     ws = jnp.asarray((rng.standard_normal((n_stages, d, d)) * 0.1),
                      jnp.float32)
@@ -1006,7 +1007,16 @@ ALL = [
 ]
 
 
+# the only benchmarks that put arrays on a JAX device in THIS process.  A
+# chip belongs to one process: they run after every benchmark that starts
+# child processes (chunked_dump_load), so no child finds the chip taken.
+ON_DEVICE = (beyond_planes_codec,)
+
+
 def main(names: list[str] | None = None) -> None:
+    from repro import compile_cache
+
+    compile_cache.enable()
     os.makedirs(OUT, exist_ok=True)
     by_name = {fn.__name__: fn for fn in ALL}
     if names:
@@ -1016,6 +1026,7 @@ def main(names: list[str] | None = None) -> None:
         todo = [by_name[n] for n in names]
     else:
         todo = ALL
+    todo = sorted(todo, key=lambda fn: fn in ON_DEVICE)
     results = {}
     print("name,us_per_call,derived")
     for fn in todo:

@@ -1,87 +1,10 @@
-"""Version-portability shims for the JAX APIs this repo straddles.
-
-The codebase targets the modern ``jax.shard_map`` entry point (``axis_names``
-selects the manual axes, ``check_vma`` toggles the varying-manual-axes check).
-Older installs (<= 0.4.x) only ship ``jax.experimental.shard_map.shard_map``
-whose equivalent knobs are ``auto`` (the complement of the manual axes) and
-``check_rep``.  Routing every call site through :func:`shard_map` keeps the
-rest of the code on one spelling.
-"""
+"""Small adapters over JAX APIs whose return shape varies."""
 from __future__ import annotations
-
-from typing import Callable
-
-import jax
-
-
-def shard_map(
-    f: Callable,
-    *,
-    mesh,
-    in_specs,
-    out_specs,
-    axis_names: set | None = None,
-    check_vma: bool = False,
-):
-    """``jax.shard_map`` with fallback to ``jax.experimental.shard_map``."""
-    if hasattr(jax, "shard_map"):
-        kw = {}
-        if axis_names is not None:
-            kw["axis_names"] = set(axis_names)
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma, **kw,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    auto: frozenset = frozenset()
-    if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - set(axis_names)
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma, auto=auto,
-    )
-
-
-def axis_size(axis_name) -> int:
-    """Static size of a mapped mesh axis, inside shard_map/pmap bodies.
-
-    Older JAX has no ``jax.lax.axis_size``; ``psum(1, axis)`` of a literal is
-    constant-folded to the (static) axis size there.
-    """
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-def partial_manual_supported() -> bool:
-    """Whether shard_map may leave some mesh axes automatic (GSPMD) while
-    others are manual.  Old XLA (no native ``jax.shard_map``) fatally asserts
-    on collectives under partial-manual regions, so callers must fall back to
-    fully-manual bodies there."""
-    return hasattr(jax, "shard_map")
-
-
-def sharding_hints_supported() -> bool:
-    """Whether with_sharding_constraint is safe at the current trace point.
-
-    Old JAX/XLA (no native ``jax.shard_map``) fatally asserts
-    (``IsManualSubgroup``) when a named-sharding constraint appears inside a
-    partial-manual shard_map region, so activation hints must be dropped
-    there; they are only hints, correctness is unaffected.
-    """
-    if hasattr(jax, "shard_map"):
-        return True
-    try:
-        from jax._src.core import get_axis_env
-
-        return not get_axis_env().axis_names()
-    except Exception:
-        return True
 
 
 def compiled_cost_analysis(compiled) -> dict:
-    """``Compiled.cost_analysis()`` across JAX versions (dict vs 1-list)."""
+    """``Compiled.cost_analysis()`` as a dict (some backends wrap it in a
+    1-list)."""
     cost = compiled.cost_analysis()
     if isinstance(cost, (list, tuple)):
         cost = cost[0]

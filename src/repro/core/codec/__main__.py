@@ -230,6 +230,9 @@ def _cmd_info(args) -> int:
 
 
 def main(argv: list[str] | None = None) -> int:
+    from repro import compile_cache
+
+    compile_cache.enable()
     ap = argparse.ArgumentParser(
         prog="python -m repro.core.codec", description=__doc__.splitlines()[0]
     )

@@ -180,7 +180,7 @@ def encode_device(xb, p: Plan) -> DeviceEncoding:
     """
     from repro.kernels import ops
 
-    backend = ops._resolve(p.backend)
+    backend = ops._resolve(p.backend, p.dtype)
     if backend == "numpy" or p.nblocks == 0 or _body_cap(p) > _INT32_SAFE:
         return _encode_host(xb, p)
     spec = p.dtype
@@ -336,10 +336,10 @@ def decode_stream(buf, *, backend: str = "auto", out=None, block_range=None):
     """
     from repro.kernels import ops
 
-    backend = ops._resolve(backend)
+    spec, bs, n, nb, nnc, nmid, prefix_len = _checked_stream_header(buf)
+    backend = ops._resolve(backend, spec)
     if backend == "numpy":
         return None
-    spec, bs, n, nb, nnc, nmid, prefix_len = _checked_stream_header(buf)
     expected = prefix_len + nmid
     if len(buf) < expected:
         raise ValueError(
@@ -394,10 +394,10 @@ def decode_range(prefix: bytes, mid: bytes, lo: int, hi: int, *,
     """
     from repro.kernels import ops
 
-    backend = ops._resolve(backend)
+    spec, bs, n, nb, nnc, nmid, prefix_len = _checked_stream_header(prefix)
+    backend = ops._resolve(backend, spec)
     if backend == "numpy":
         return None
-    spec, bs, n, nb, nnc, nmid, prefix_len = _checked_stream_header(prefix)
     cap = nb and (
         (nb + 7) // 8 + spec.itemsize * nb + nb + (nb * bs + 3) // 4
         + nb * bs * spec.itemsize

@@ -146,7 +146,7 @@ class SZxCodec:
         from repro.kernels import ops
 
         xb = plan_mod.to_blocks(xt, p)
-        if ops._resolve(p.backend) == "numpy":
+        if ops._resolve(p.backend, p.dtype) == "numpy":
             # host mirror: encode + serialize entirely in numpy (byte-identical
             # to the device path; this is also the benchmark hot path)
             enc = transform.encode_blocks(xb, p)

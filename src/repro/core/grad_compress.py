@@ -20,7 +20,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro import compat, obs
+from repro import obs
 from repro.core.codec import PlanesCodec
 
 DEFAULT_BLOCK = 64
@@ -72,7 +72,7 @@ def compressed_psum_mean(grads, axis_name: str, *, num_planes: int = 1,
 
     Returns the mean of the decoded per-member gradients plus this member's
     compression residual (for error feedback)."""
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
 
     def leaf(g):
         enc = _encode_leaf(g, num_planes, block, backend)
@@ -127,7 +127,7 @@ def compressed_all_to_all(x, axis_name: str, split_axis: int, concat_axis: int,
         raise ValueError(
             "compressed_all_to_all cannot split/concat the blocked last axis"
         )
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     enc = _encode_leaf(x, num_planes, block, backend)
     _record_wire("all_to_all", x, enc)
 

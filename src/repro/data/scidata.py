@@ -9,6 +9,8 @@ Table III.
 """
 from __future__ import annotations
 
+import zlib
+
 import numpy as np
 
 
@@ -59,7 +61,9 @@ APPLICATIONS = {
 
 def field(app: str, idx: int) -> np.ndarray:
     spec = APPLICATIONS[app]
-    rng = np.random.default_rng(np.random.SeedSequence([hash(app) % 2**31, idx]))
+    # a stable digest of the name (not hash(), which PYTHONHASHSEED salts):
+    # every process generates the same field
+    rng = np.random.default_rng(np.random.SeedSequence([zlib.crc32(app.encode()), idx]))
     rough = spec["rough"] * (1.0 + 0.25 * (idx - spec["fields"] / 2) / spec["fields"])
     f = _smooth_field(rng, spec["shape"], spec["octaves"], rough, spec["spikes"])
     scale = 10.0 ** rng.integers(-2, 4)

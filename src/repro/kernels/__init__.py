@@ -3,17 +3,17 @@
 All transform kernels are width-generic: parameterized by a
 :class:`repro.kernels.specs.DtypeSpec` so one implementation covers
 float32/float64/float16/bfloat16 (f64 needs 64-bit words; the dispatch layer
-runs it under ``jax.experimental.enable_x64`` and falls back to the jitted
-oracle on real TPUs, which have no 64-bit words).
+runs it under ``jax.enable_x64(True)``, and only off-TPU: TPUs have no
+64-bit words).
 
 Modules:
   specs.py       -- DtypeSpec: storage + compute IEEE-754 geometry
   ref.py         -- pure-jnp oracles (ground truth)
-  block_stats.py -- per-block min/max/mu/radius/reqlen (Alg. 1 lines 3-7)
-  pack.py        -- normalize + Solution-C shift + XOR-lead + byte planes
+  tiles.py       -- row tiling, meta rows, integer 16-bit float conversion
   encode.py      -- FUSED stats+pack (one kernel, one round trip per chunk)
-  unpack.py      -- decompression with log-time index propagation (Fig. 9)
-                    + the all-L==0 dense fast path
+  unpack.py      -- the decode kernel: log-time index propagation (Fig. 9)
+                    + the all-L==0 dense variant
   planes.py      -- szx-planes fixed-plane encode/decode (in-graph mode)
+  bitshuffle.py  -- bit transpose for the lossless second stage
   ops.py         -- jit'd wrappers + backend dispatch
 """

@@ -11,9 +11,9 @@ Backends:
 Every transform op takes a ``spec`` (:class:`repro.kernels.specs.DtypeSpec`,
 default float32) and all three backends are bit-identical per spec.  float64
 needs 64-bit words, which jax disables by default, so the jax/kernel routes
-wrap those calls in ``jax.experimental.enable_x64``; on a real TPU (no 64-bit
-words in hardware) the f64 'kernel' route falls through to the jitted oracle
-with a one-time warning.
+run those calls under ``jax.enable_x64(True)``.  TPUs have no 64-bit words:
+there 'auto' resolves float64 to 'numpy' (the host mirror), and an explicit
+'jax' or 'kernel' for float64 raises.
 
 ``encode`` is the fused stats+pack op: one traced program and a single
 host<->device round trip instead of two, which is what the chunked codec hot
@@ -24,7 +24,6 @@ from __future__ import annotations
 import contextlib
 import functools
 import os
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -33,51 +32,40 @@ import numpy as np
 from repro.kernels import ref, specs
 from repro.kernels.specs import DtypeSpec
 
-_WARNED: set = set()
 
+def _resolve(backend: str, spec: DtypeSpec | None = None) -> str:
+    """Concrete backend for ``backend`` (and, when given, the dtype ``spec``).
 
-def _warn_once(key: str, msg: str) -> None:
-    if key not in _WARNED:
-        _WARNED.add(key)
-        warnings.warn(msg, RuntimeWarning, stacklevel=3)
-
-
-def _resolve(backend: str) -> str:
+    On TPU, float64 resolves 'auto' to 'numpy' and rejects 'jax'/'kernel':
+    the chip has no 64-bit words, so no device program exists for it.
+    'numpy' never queries (and so never initializes) a JAX backend.
+    """
+    if backend == "numpy":
+        return backend
+    on_tpu = jax.default_backend() == "tpu"
+    no_device = spec is not None and spec.needs_x64 and on_tpu
     if backend == "auto":
+        if no_device:
+            return "numpy"
         backend = os.environ.get("SZX_OPS_BACKEND") or (
-            "kernel" if jax.default_backend() == "tpu" else "jax"
+            "kernel" if on_tpu else "jax"
         )
     if backend not in ("jax", "kernel", "numpy"):
         raise ValueError(
             f"unknown SZx ops backend {backend!r}; "
             "expected 'jax', 'kernel', 'numpy', or 'auto'"
         )
+    if no_device and backend != "numpy":
+        raise ValueError(
+            f"SZx {spec.name} has no {backend!r} route on TPU (no 64-bit "
+            "words); use backend='numpy' or 'auto'"
+        )
     return backend
 
 
 def _x64_scope(spec: DtypeSpec):
     """Context enabling 64-bit words for specs that need them (float64)."""
-    if spec.needs_x64:
-        from jax.experimental import enable_x64
-
-        return enable_x64()
-    return contextlib.nullcontext()
-
-
-def _kernel_route(spec: DtypeSpec, op: str) -> bool:
-    """True if the Pallas route can run this spec here; warn + False if not.
-
-    TPUs have no 64-bit words, so native (non-interpret) f64 kernels cannot
-    compile; everywhere else the kernels run (natively or interpreted).
-    """
-    if spec.needs_x64 and jax.default_backend() == "tpu":
-        _warn_once(
-            f"kernel-f64-{op}",
-            f"SZx '{op}' has no 64-bit Pallas kernel on TPU; "
-            "falling back to the jitted jnp oracle for float64",
-        )
-        return False
-    return True
+    return jax.enable_x64(True) if spec.needs_x64 else contextlib.nullcontext()
 
 
 # --------------------------------------------------------------------------
@@ -371,13 +359,13 @@ def _as_words(xb, spec: DtypeSpec):
 
 
 def block_stats(xb, e, *, spec: DtypeSpec = specs.F32, backend: str = "auto"):
-    backend = _resolve(backend)
+    backend = _resolve(backend, spec)
     if backend == "numpy":
         return _block_stats_np(xb, e, spec)
     p_e = specs.exact_exponent_of(float(e))
     with _x64_scope(spec):
-        if backend == "kernel" and _kernel_route(spec, "block_stats"):
-            from repro.kernels import block_stats as k
+        if backend == "kernel":
+            from repro.kernels import encode as k
 
             return k.block_stats(
                 _as_words(xb, spec),
@@ -394,7 +382,7 @@ def block_stats(xb, e, *, spec: DtypeSpec = specs.F32, backend: str = "auto"):
 
 
 def pack(xb, mu, shift, nbytes, *, spec: DtypeSpec = specs.F32, backend: str = "auto"):
-    backend = _resolve(backend)
+    backend = _resolve(backend, spec)
     if backend == "numpy":
         return _pack_np(
             np.asarray(xb), np.asarray(mu), np.asarray(shift), np.asarray(nbytes),
@@ -407,8 +395,8 @@ def pack(xb, mu, shift, nbytes, *, spec: DtypeSpec = specs.F32, backend: str = "
             jnp.asarray(shift, jnp.int32),
             jnp.asarray(nbytes, jnp.int32),
         )
-        if backend == "kernel" and _kernel_route(spec, "pack"):
-            from repro.kernels import pack as k
+        if backend == "kernel":
+            from repro.kernels import encode as k
 
             return k.pack(*args, spec=spec)
         return _pack_jax(*args, spec)
@@ -427,7 +415,7 @@ def encode_staged(xb, e, p_e, *, spec: DtypeSpec = specs.F32, backend: str = "ja
         raise ValueError(
             f"encode_staged needs a resolved device backend, got {backend!r}"
         )
-    if backend == "kernel" and _kernel_route(spec, "encode"):
+    if backend == "kernel":
         from repro.kernels import encode as k
 
         return k.encode(xb, e, p_e, spec=spec)
@@ -445,12 +433,14 @@ def decode_staged(body, nnc, lo=0, *, spec: DtypeSpec = specs.F32, nb: int,
     header stripped, zero-padded to a static capacity); ``nnc`` the header's
     n_nonconst field and ``lo`` the first decoded block, both traced; ``nb``/
     ``bs``/``rb`` static.  Parses the metadata sections on device
-    (``ref.parse_body_ref``) then runs the fused unpack+compose -- the
-    single-``pallas_call`` kernel on the 'kernel' route, the jnp oracle on
-    'jax'.  Returns (vals (rb, bs), measured (3,) int32): the bitmap's
-    nonconst count, the max per-block nbytes, and the L-implied mid-stream
-    total -- checked against the header fields on the host after its single
-    readback (the device-side half of ``container.parse_stream`` validation).
+    (``ref.parse_body_ref``), gathers each value's stored bytes out of the
+    body (``ref.gather_words_ref``, XLA: Mosaic has no 1-D dynamic gather)
+    and composes the values -- the Pallas decode kernel on the 'kernel'
+    route, the jnp oracle on 'jax'.  Returns (vals (rb, bs), measured (3,)
+    int32): the bitmap's nonconst count, the max per-block nbytes, and the
+    L-implied mid-stream total -- checked against the header fields on the
+    host after its single readback (the device-side half of
+    ``container.parse_stream`` validation).
     """
     if backend not in ("jax", "kernel"):
         raise ValueError(
@@ -461,18 +451,17 @@ def decode_staged(body, nnc, lo=0, *, spec: DtypeSpec = specs.F32, nb: int,
     _const, mu, shift, nbytes, rank, nnc_seen = ref.parse_body_ref(
         body, nnc, spec, nb
     )
-    if backend == "kernel" and _kernel_route(spec, "decode"):
-        from repro.kernels import decode as k
+    words, L, mid_total = ref.gather_words_ref(
+        body, nnc, lo, nbytes, rank, spec, bs=bs, rb=rb, rebase=rebase
+    )
+    args = [jax.lax.dynamic_slice_in_dim(a, lo, rb, axis=0)
+            for a in (mu, shift, nbytes)]
+    if backend == "kernel":
+        from repro.kernels import unpack as k
 
-        vals, mid_total = k.decode_body(
-            body, nnc, lo, mu, shift, nbytes, rank,
-            spec=spec, bs=bs, rb=rb, rebase=rebase,
-        )
+        vals = k.unpack_words(words, *args, L, spec=spec)
     else:
-        vals, mid_total = ref.decode_body_ref(
-            body, nnc, lo, mu, shift, nbytes, rank, spec,
-            bs=bs, rb=rb, rebase=rebase,
-        )
+        vals = ref.unpack_words_ref(words, *args, L, spec)
     measured = jnp.stack(
         [nnc_seen, jnp.max(nbytes).astype(jnp.int32), mid_total]
     )
@@ -486,7 +475,7 @@ def encode(xb, e, *, spec: DtypeSpec = specs.F32, backend: str = "auto"):
     host<->device round trip) instead of the two-call stats-then-pack
     sequence; bit-identical to calling :func:`block_stats` + :func:`pack`.
     """
-    backend = _resolve(backend)
+    backend = _resolve(backend, spec)
     if backend == "numpy":
         return _encode_np(xb, e, spec)
     p_e = specs.exact_exponent_of(float(e))
@@ -496,7 +485,7 @@ def encode(xb, e, *, spec: DtypeSpec = specs.F32, backend: str = "auto"):
             jnp.asarray(float(e), spec.compute_np_dtype),
             jnp.int32(p_e),
         )
-        if backend == "kernel" and _kernel_route(spec, "encode"):
+        if backend == "kernel":
             from repro.kernels import encode as k
 
             return k.encode(*args, spec=spec)
@@ -508,7 +497,7 @@ def unpack(planes, mu, shift, nbytes, L, *, spec: DtypeSpec = specs.F32,
     """Inverse of :func:`pack`.  With ``out`` (a (nb, bs) array in the spec's
     dtype) the reconstruction is written into the caller's buffer and ``out``
     is returned -- allocation-free on the numpy route, one copy elsewhere."""
-    backend = _resolve(backend)
+    backend = _resolve(backend, spec)
     if backend == "numpy":
         return _unpack_np(
             np.asarray(planes),
@@ -527,7 +516,7 @@ def unpack(planes, mu, shift, nbytes, L, *, spec: DtypeSpec = specs.F32,
             jnp.asarray(nbytes, jnp.int32),
             jnp.asarray(L, jnp.int32),
         )
-        if backend == "kernel" and _kernel_route(spec, "unpack"):
+        if backend == "kernel":
             from repro.kernels import unpack as k
 
             res = k.unpack(*args, spec=spec)
@@ -545,7 +534,7 @@ def unpack_dense(planes, mu, shift, nbytes, *, spec: DtypeSpec = specs.F32,
     byte sits at its own value, so decode skips the per-byte index-propagation
     scan entirely.  Bit-identical to ``unpack(..., L=0)``.
     """
-    backend = _resolve(backend)
+    backend = _resolve(backend, spec)
     if backend == "numpy":
         return _unpack_dense_np(
             np.asarray(planes), np.asarray(mu), np.asarray(shift),
@@ -558,7 +547,7 @@ def unpack_dense(planes, mu, shift, nbytes, *, spec: DtypeSpec = specs.F32,
             jnp.asarray(shift, jnp.int32),
             jnp.asarray(nbytes, jnp.int32),
         )
-        if backend == "kernel" and _kernel_route(spec, "unpack_dense"):
+        if backend == "kernel":
             from repro.kernels import unpack as k
 
             res = k.unpack_dense(*args, spec=spec)
@@ -663,7 +652,7 @@ def bitshuffle(tiles, *, spec: DtypeSpec = specs.F32, inverse: bool = False,
     backend = _resolve(backend)
     if backend == "numpy":
         return _bitshuffle_np(np.asarray(tiles), inverse)
-    if backend == "kernel" and _kernel_route(spec, "bitshuffle"):
+    if backend == "kernel":
         from repro.kernels import bitshuffle as k
 
         return k.bitshuffle(

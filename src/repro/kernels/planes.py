@@ -1,14 +1,13 @@
 """Pallas TPU kernels: szx-planes fixed-plane encode/decode.
 
 The in-graph (static-shape) SZx variant used for gradient/KV compression:
-per-block mu + radius-exponent-derived scale + P uint8 quantization planes.
-Previously the 'kernel' backend silently routed to the jitted jnp oracle;
-these kernels give it a real Pallas route (oracle:
-``ref.planes_encode_ref`` / ``ref.planes_decode_ref``, bit-identical).
+per-block mu + radius-exponent-derived scale + P uint8 quantization planes
+(oracle: ``ref.planes_encode_ref`` / ``ref.planes_decode_ref``, bit-identical).
 
-Shapes: the ops layer flattens leading dims to (nb, bs) before the call and
-restores them after, so the kernels only ever see 2-D tiles
-(TILE_BLOCKS=8 blocks x bs lanes, float32 -- szx-planes is an f32-only mode).
+Shapes: leading dims are flattened to (nb, bs) before the call and restored
+after, so the kernels only ever see ``(TILE_BLOCKS, bs)`` row tiles of
+float32 (szx-planes is an f32-only mode); mu and sexp travel as meta rows
+(see :mod:`repro.kernels.tiles`).
 """
 from __future__ import annotations
 
@@ -18,17 +17,17 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-TILE_BLOCKS = 8
+from repro.kernels import tiles
 
 
 def _make_encode_kernel(num_planes: int):
     nbits = 8 * num_planes
     lim = float(2.0 ** (nbits - 1))   # python literal: closure-safe in pallas
 
-    def _kernel(x_ref, mu_ref, sexp_ref, planes_ref):
+    def _kernel(x_ref, planes_ref, meta_ref):
         x = x_ref[...]                                   # (TB, bs) f32
-        mn = jnp.min(x, axis=1)
-        mx = jnp.max(x, axis=1)
+        mn = jnp.min(x, axis=1, keepdims=True)
+        mx = jnp.max(x, axis=1, keepdims=True)
         mu = 0.5 * (mn + mx)
         radius = jnp.maximum(mx - mu, mu - mn)
         E = (
@@ -36,14 +35,13 @@ def _make_encode_kernel(num_planes: int):
             & jnp.uint32(0xFF)
         ).astype(jnp.int32) - 127
         sexp = (nbits - 2) - E
-        v = x - mu[:, None]
-        scale = jnp.exp2(sexp.astype(jnp.float32))[:, None]
-        q = jnp.clip(jnp.round(v * scale), -lim, lim - 1).astype(jnp.int32)
-        uq = q.astype(jnp.uint32)
+        scale = jnp.exp2(sexp.astype(jnp.float32))
+        q = jnp.clip(jnp.round((x - mu) * scale), -lim, lim - 1).astype(jnp.int32)
         for p in range(num_planes):
-            planes_ref[p, :, :] = ((uq >> (8 * p)) & jnp.uint32(0xFF)).astype(jnp.uint8)
-        mu_ref[...] = mu
-        sexp_ref[...] = sexp
+            planes_ref[p, :, :] = ((q >> (8 * p)) & 0xFF).astype(jnp.uint8)
+        meta_ref[...] = tiles.cols_to_rows(
+            [jax.lax.bitcast_convert_type(mu, jnp.int32), sexp], jnp.int32
+        )
 
     return _kernel
 
@@ -51,28 +49,29 @@ def _make_encode_kernel(num_planes: int):
 def _make_decode_kernel(num_planes: int):
     nbits = 8 * num_planes
 
-    def _kernel(planes_ref, mu_ref, sexp_ref, out_ref):
-        planes = planes_ref[...]                         # (P, TB, bs) u8
-        mu = mu_ref[...]
-        sexp = sexp_ref[...]
-        uq = jnp.zeros(planes.shape[1:], jnp.int32)
-        for p in range(num_planes):
-            uq = uq | (planes[p].astype(jnp.int32) << (8 * p))
+    def _kernel(meta_ref, planes_ref, out_ref):
+        mu_m, sexp = tiles.rows_to_cols(meta_ref[...], 2)
+        mu = jax.lax.bitcast_convert_type(mu_m, jnp.float32)
+        uq = planes_ref[0].astype(jnp.int32)
+        for p in range(1, num_planes):
+            uq = uq | (planes_ref[p].astype(jnp.int32) << (8 * p))
         # sign-extend a width-`nbits` two's-complement integer (fits in int32)
         q = jnp.where(uq >= (1 << (nbits - 1)), uq - (1 << nbits), uq).astype(
             jnp.float32
         )
-        out_ref[...] = q * jnp.exp2(-sexp.astype(jnp.float32))[:, None] + mu[:, None]
+        out_ref[...] = q * jnp.exp2(-sexp.astype(jnp.float32)) + mu
 
     return _kernel
+
+
+def _planes_spec(num_planes: int, tb: int, bs: int) -> pl.BlockSpec:
+    return pl.BlockSpec((num_planes, tb, bs), lambda i: (0, i, 0))
 
 
 @functools.partial(jax.jit, static_argnames=("num_planes", "interpret"))
 def planes_encode(xb, num_planes: int, *, interpret: bool | None = None):
     """Same contract as ref.planes_encode_ref; xb may have leading dims."""
     assert 1 <= num_planes <= 3, "szx-planes supports 1..3 byte planes"
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     xb = jnp.asarray(xb, jnp.float32)
     lead = xb.shape[:-1]
     bs = xb.shape[-1]
@@ -81,29 +80,21 @@ def planes_encode(xb, num_planes: int, *, interpret: bool | None = None):
     if nb == 0:
         return (jnp.zeros(lead, jnp.float32), jnp.zeros(lead, jnp.int32),
                 jnp.zeros((num_planes,) + lead + (bs,), jnp.uint8))
-    pad = (-nb) % TILE_BLOCKS
-    if pad:
-        x2 = jnp.pad(x2, ((0, pad), (0, 0)))
-    nbp = nb + pad
-    grid = (nbp // TILE_BLOCKS,)
-    vec = pl.BlockSpec((TILE_BLOCKS,), lambda i: (i,))
-    mu, sexp, planes = pl.pallas_call(
+    rows, tb = tiles.row_tiles(nb)
+    planes, meta = pl.pallas_call(
         _make_encode_kernel(num_planes),
-        grid=grid,
-        in_specs=[pl.BlockSpec((TILE_BLOCKS, bs), lambda i: (i, 0))],
-        out_specs=(
-            vec,
-            vec,
-            pl.BlockSpec((num_planes, TILE_BLOCKS, bs), lambda i: (0, i, 0)),
-        ),
+        grid=(pl.cdiv(rows, tb),),
+        in_specs=[tiles.row_spec(tb, bs)],
+        out_specs=(_planes_spec(num_planes, tb, bs), tiles.meta_spec(tb)),
         out_shape=(
-            jax.ShapeDtypeStruct((nbp,), jnp.float32),
-            jax.ShapeDtypeStruct((nbp,), jnp.int32),
-            jax.ShapeDtypeStruct((num_planes, nbp, bs), jnp.uint8),
+            jax.ShapeDtypeStruct((num_planes, rows, bs), jnp.uint8),
+            jax.ShapeDtypeStruct((tiles.META_ROWS, rows), jnp.int32),
         ),
-        interpret=interpret,
-    )(x2)
-    return (mu[:nb].reshape(lead), sexp[:nb].reshape(lead),
+        interpret=tiles.interpret_default(interpret),
+        name="szx_planes_encode",
+    )(tiles.pad_rows(x2, rows))
+    mu = jax.lax.bitcast_convert_type(meta[0, :nb], jnp.float32)
+    return (mu.reshape(lead), meta[1, :nb].reshape(lead),
             planes[:, :nb].reshape((num_planes,) + lead + (bs,)))
 
 
@@ -112,34 +103,26 @@ def planes_decode(mu, sexp, planes, *, interpret: bool | None = None):
     """Same contract as ref.planes_decode_ref -> (..., bs) f32."""
     num_planes = planes.shape[0]
     assert num_planes <= 3, "szx-planes supports 1..3 byte planes"
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     lead = planes.shape[1:-1]
     bs = planes.shape[-1]
     p2 = planes.reshape(num_planes, -1, bs)
     nb = p2.shape[1]
     if nb == 0:
         return jnp.zeros(lead + (bs,), jnp.float32)
-    mu2 = jnp.asarray(mu, jnp.float32).reshape(-1)
-    sexp2 = jnp.asarray(sexp, jnp.int32).reshape(-1)
-    pad = (-nb) % TILE_BLOCKS
-    if pad:
-        p2 = jnp.pad(p2, ((0, 0), (0, pad), (0, 0)))
-        mu2 = jnp.pad(mu2, (0, pad))
-        sexp2 = jnp.pad(sexp2, (0, pad))
-    nbp = nb + pad
-    grid = (nbp // TILE_BLOCKS,)
-    vec = pl.BlockSpec((TILE_BLOCKS,), lambda i: (i,))
+    rows, tb = tiles.row_tiles(nb)
+    meta = tiles.meta_rows(
+        [jax.lax.bitcast_convert_type(jnp.asarray(mu, jnp.float32).reshape(-1),
+                                      jnp.int32),
+         jnp.asarray(sexp, jnp.int32).reshape(-1)],
+        rows, jnp.int32,
+    )
     out = pl.pallas_call(
         _make_decode_kernel(num_planes),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((num_planes, TILE_BLOCKS, bs), lambda i: (0, i, 0)),
-            vec,
-            vec,
-        ],
-        out_specs=pl.BlockSpec((TILE_BLOCKS, bs), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nbp, bs), jnp.float32),
-        interpret=interpret,
-    )(p2, mu2, sexp2)
+        grid=(pl.cdiv(rows, tb),),
+        in_specs=[tiles.meta_spec(tb), _planes_spec(num_planes, tb, bs)],
+        out_specs=tiles.row_spec(tb, bs),
+        out_shape=jax.ShapeDtypeStruct((rows, bs), jnp.float32),
+        interpret=tiles.interpret_default(interpret),
+        name="szx_planes_decode",
+    )(meta, tiles.pad_rows(p2, rows, axis=1))
     return out[:nb].reshape(lead + (bs,))

@@ -1,7 +1,7 @@
 """Pure-jnp oracles for the width-generic SZx block-compression kernels.
 
 These functions are the ground-truth semantics for the Pallas kernels in
-``block_stats.py`` / ``pack.py`` / ``unpack.py`` / ``encode.py``.  Everything
+``encode.py`` / ``unpack.py`` / ``planes.py`` / ``bitshuffle.py``.  Everything
 here is fixed-shape (the variable-length byte compaction happens at the
 host/serialization boundary in ``repro.core.codec.container``), which is what
 makes the algorithm expressible on TPU.
@@ -265,20 +265,20 @@ def parse_body_ref(body, nnc, spec: DtypeSpec, nb: int):
     return const, mu, shift, nbytes, rank, incl[-1]
 
 
-def decode_body_ref(body, nnc, lo, mu, shift, nbytes, rank, spec: DtypeSpec,
-                    *, bs: int, rb: int, rebase: bool = False):
-    """Fused unpack+compose straight from raw body bytes (decode oracle).
+def gather_words_ref(body, nnc, lo, nbytes, rank, spec: DtypeSpec,
+                     *, bs: int, rb: int, rebase: bool = False):
+    """Gather each value's stored bytes straight out of raw body bytes.
 
-    Expands the compacted 2-bit L codes, derives each value's mid-stream
-    offset as the exclusive cumsum of ``nbytes - L``, gathers the stored
-    bytes directly out of ``body`` (no intermediate planes array), runs the
-    XOR-lead index propagation as a fused-key cummax, and composes via
-    :func:`_compose_word`.  ``lo`` is the first decoded block (traced);
-    ``rb`` (static) blocks are produced.  ``rebase=True`` reads the mid
-    section as starting at block ``lo``'s first mid byte -- the store ROI
-    buffer layout (metadata prefix + the requested blocks' mid range).
+    Expands the compacted 2-bit L codes and derives each value's mid-stream
+    offset as the exclusive cumsum of ``nbytes - L``, then gathers the stored
+    bytes out of ``body`` (no intermediate planes array).  ``lo`` is the
+    first decoded block (traced); ``rb`` (static) blocks are produced.
+    ``rebase=True`` reads the mid section as starting at block ``lo``'s
+    first mid byte -- the store ROI buffer layout (metadata prefix + the
+    requested blocks' mid range).
 
-    Returns (vals (rb, bs) in the spec's dtype, mid_total int32): the
+    Returns (words (rb, bs) ``spec.uint_dtype`` holding the stored bytes of
+    each value (0 where elided), L (rb, bs) int32, mid_total int32): the
     full-stream mid byte count implied by the L codes, for host-side
     validation against the header's nmid after the single readback.
     """
@@ -296,11 +296,14 @@ def decode_body_ref(body, nnc, lo, mu, shift, nbytes, rank, spec: DtypeSpec,
     lidx = jnp.clip(jnp.where(live_blk, l_off + pos // 4, 0), 0, cap - 1)
     code = (body[lidx].astype(jnp.int32) >> ((pos % 4) * 2)) & 3
     L = jnp.where(live_blk, code, 0)
-    # mid-stream offsets: exclusive cumsum of per-value stored-byte counts
+    # mid-stream offsets: exclusive cumsum of per-value stored-byte counts,
+    # as a cumsum within each block plus one across the block totals
     counts = jnp.maximum(nbytes[:, None] - L, 0)
-    ends = jnp.cumsum(counts.reshape(-1)).reshape(nb, bs)
+    row = jnp.cumsum(counts, axis=1)
+    blk = jnp.cumsum(row[:, -1])
+    ends = row + (blk - row[:, -1])[:, None]
     start = ends - counts
-    mid_total = ends.reshape(-1)[-1]
+    mid_total = blk[-1]
     base = mid_off - (
         jax.lax.dynamic_slice_in_dim(start, lo, 1, axis=0)[0, 0] if rebase else 0
     )
@@ -308,16 +311,29 @@ def decode_body_ref(body, nnc, lo, mu, shift, nbytes, rank, spec: DtypeSpec,
     def sl(a):
         return jax.lax.dynamic_slice_in_dim(a, lo, rb, axis=0)
 
-    L, start = sl(L), sl(start)
-    nbytes_r, shift_r, mu_r = sl(nbytes), sl(shift), sl(mu)
+    L, start, nbytes_r = sl(L), sl(start), sl(nbytes)
+    udt = spec.uint_dtype
+    words = jnp.zeros((rb, bs), udt)
+    for j in range(W):
+        stored = (L <= j) & (j < nbytes_r[:, None])
+        gidx = jnp.clip(jnp.where(stored, base + start + (j - L), 0), 0, cap - 1)
+        byte = jnp.where(stored, body[gidx], jnp.uint8(0)).astype(udt)
+        words = words | (byte << jnp.asarray(8 * (W - 1 - j), udt))
+    return words, L, mid_total
+
+
+def unpack_words_ref(words, mu, shift, nbytes, L, spec: DtypeSpec):
+    """:func:`unpack_ref` over stored-byte words (bytes a value elides are
+    ignored): the fused-key cummax form of the index propagation."""
+    rb, bs = words.shape
+    W = spec.itemsize
     udt = spec.uint_dtype
     idxs = jnp.broadcast_to(jnp.arange(bs, dtype=jnp.int32)[None, :], (rb, bs))
     ws = jnp.zeros((rb, bs), udt)
     for j in range(W):
         sh = jnp.asarray(8 * (W - 1 - j), udt)
-        stored = (L <= j) & (j < nbytes_r[:, None])
-        gidx = jnp.clip(jnp.where(stored, base + start + (j - L), 0), 0, cap - 1)
-        byte = jnp.where(stored, body[gidx].astype(jnp.int32), 0)
+        stored = (L <= j) & (j < nbytes[:, None])
+        byte = jnp.where(stored, ((words >> sh) & 0xFF).astype(jnp.int32), 0)
         if j >= spec.lead_cap:
             # L <= lead_cap <= j: every live value stores this plane itself
             ws = ws | (byte.astype(udt) << sh)
@@ -328,7 +344,7 @@ def decode_body_ref(body, nnc, lo, mu, shift, nbytes, rank, spec: DtypeSpec,
         key = jax.lax.cummax(key, axis=1)
         b = jnp.where(key >= 0, (key & 0xFF).astype(udt), jnp.asarray(0, udt))
         ws = ws | (b << sh)
-    return _compose_word(ws, mu_r, shift_r, nbytes_r, spec), mid_total
+    return _compose_word(ws, mu, shift, nbytes, spec)
 
 
 # ---------------------------------------------------------------------------
@@ -390,11 +406,13 @@ def bitshuffle_ref(tiles, *, inverse: bool = False):
     Bit-identical to ``np.unpackbits``/``np.packbits`` with
     ``bitorder='little'`` (pinned by tests against the numpy mirror).
     """
-    from repro.kernels.bitshuffle import shuffle_body
+    from repro.kernels.bitshuffle import from_matrix, to_matrix, transpose8
 
     nt, T = tiles.shape
     if T % 8:
         raise ValueError(f"bitshuffle tile width {T} is not a multiple of 8")
     if nt == 0:
         return jnp.zeros((0, T), jnp.uint8)
-    return shuffle_body(jnp.asarray(tiles, jnp.uint8), inverse=inverse)
+    mat = to_matrix(jnp.asarray(tiles, jnp.uint8), inverse).astype(jnp.int32)
+    out = jnp.stack(transpose8([mat[j] for j in range(8)])).astype(jnp.uint8)
+    return from_matrix(out, nt, inverse)

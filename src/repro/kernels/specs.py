@@ -17,7 +17,7 @@ path); the scalar error-bound exponent ``p(e)`` is computed exactly on the
 host (``math.frexp``) and passed into the kernels.
 
 float64 needs 64-bit words, which jax disables by default; the dispatch layer
-(``repro.kernels.ops``) wraps those calls in ``jax.experimental.enable_x64``.
+(``repro.kernels.ops``) runs those calls under ``jax.enable_x64(True)``.
 This module is the bottom of the stack: it must not import from
 ``repro.core``.
 """

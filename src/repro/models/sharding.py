@@ -13,7 +13,6 @@ import threading
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 
 _state = threading.local()
 
@@ -65,7 +64,7 @@ def rules_active() -> bool:
 
 def shard_activation(x, logical_axes):
     ctx = getattr(_state, "ctx", None)
-    if ctx is None or not compat.sharding_hints_supported():
+    if ctx is None:
         return x
     mesh, rules = ctx
     axes = []

@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro import obs
-from repro.compat import shard_map
 
 
 def pipeline_apply(
@@ -112,7 +111,7 @@ def pipeline_apply(
         # broadcasts them so the P() out_spec is truthful
         return jax.lax.psum(outs, stage_axis)
 
-    return shard_map(
+    return jax.shard_map(
         run,
         mesh=mesh,
         in_specs=(P(stage_axis), P()),

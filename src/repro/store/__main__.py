@@ -144,6 +144,9 @@ def _cmd_serve(args) -> int:
 
 
 def main(argv: list[str] | None = None) -> int:
+    from repro import compile_cache
+
+    compile_cache.enable()
     ap = argparse.ArgumentParser(
         prog="python -m repro.store", description=__doc__.splitlines()[0]
     )

@@ -354,7 +354,7 @@ class CompressedArray:
         if device:
             from repro.kernels import ops
 
-            if ops._resolve(backend) == "numpy":
+            if ops._resolve(backend, spec) == "numpy":
                 raise ValueError(
                     "device=True needs a device backend ('jax'/'kernel'), "
                     f"got {backend!r}"

@@ -14,8 +14,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
-from repro.compat import shard_map
 from repro.configs.base import ArchConfig
 from repro.core import grad_compress
 from repro.models import transformer as T
@@ -63,21 +61,12 @@ def make_train_step(cfg: ArchConfig, opt: AdamW, *, mesh=None, compress_planes: 
         return train_step
 
     assert mesh is not None and "pod" in mesh.axis_names
-    # Preferred layout: only 'pod' is manual; 'data'/'model' stay automatic so
-    # GSPMD keeps the intra-pod DP/TP shardings.  Old XLA cannot compile
-    # collectives under partial-manual regions, so there we go fully manual:
-    # the batch is split over 'data' explicitly, the intra-pod gradient mean
-    # becomes an explicit full-precision pmean('data'), and the 'model' axis
-    # computes redundantly (params replicated) -- same math, no TP overlap.
-    partial = compat.partial_manual_supported()
-    data_axes = tuple(a for a in ("data",) if a in mesh.axis_names)
+    # only 'pod' is manual; 'data'/'model' stay automatic so GSPMD keeps the
+    # intra-pod DP/TP shardings
 
     def per_pod(params, ef, batch):
         ef = jax.tree.map(lambda e: e[0], ef)            # strip sharded pod dim
         loss, grads = jax.value_and_grad(loss_of)(params, batch)
-        if not partial and data_axes:
-            loss = jax.lax.pmean(loss, data_axes)
-            grads = jax.tree.map(lambda g: jax.lax.pmean(g, data_axes), grads)
         g_eff = jax.tree.map(
             lambda g, e: g.astype(jnp.float32) + e.astype(jnp.float32), grads, ef
         )
@@ -88,12 +77,11 @@ def make_train_step(cfg: ArchConfig, opt: AdamW, *, mesh=None, compress_planes: 
         resid = jax.tree.map(lambda r: r.astype(jnp.bfloat16)[None], resid)
         return loss, mean, resid
 
-    batch_spec = P("pod") if partial else P(("pod",) + data_axes)
-    inner = shard_map(
+    inner = jax.shard_map(
         per_pod,
         mesh=mesh,
-        axis_names={"pod"} if partial else set(mesh.axis_names),
-        in_specs=(P(), P("pod"), batch_spec),
+        axis_names={"pod"},
+        in_specs=(P(), P("pod"), P("pod")),
         out_specs=(P(), P(), P("pod")),
         check_vma=False,
     )

@@ -33,11 +33,12 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import dataclasses, numpy as np
 import jax, jax.numpy as jnp
 from repro import configs
+from repro.launch.mesh import make_mesh
 from repro.models import sharding as shard_rules
 from repro.optim import AdamW
 from repro.train import step as step_mod
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 cfg = dataclasses.replace(configs.get("llama3.2-1b").reduced(), n_layers=2)
 opt = AdamW(lr=1e-2)
 
@@ -85,7 +86,6 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import numpy as np
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
 from repro.core import grad_compress as gc
 
 mesh = jax.make_mesh((4,), ("x",))
@@ -93,7 +93,7 @@ rng = np.random.default_rng(1)
 perm = [(i, (i + 1) % 4) for i in range(4)]
 
 def run(fn, x):
-    return np.asarray(shard_map(
+    return np.asarray(jax.shard_map(
         fn, mesh=mesh, in_specs=(P("x"),), out_specs=P("x"),
         axis_names={"x"}, check_vma=False,
     )(x))

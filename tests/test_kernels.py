@@ -108,3 +108,39 @@ def test_special_values(special):
     planes, L, mid = ops.pack(x, mu, shift, nbytes, backend="jax")
     y = np.asarray(ops.unpack(planes, mu, shift, nbytes, L, backend="jax"))
     assert np.abs(y - x).max() <= e
+
+
+@pytest.mark.parametrize("name", ["float16", "bfloat16"])
+def test_tile_16bit_conversions_match_numpy(name):
+    """The kernels hold 16-bit floats as integer bits (Mosaic has no f16
+    vectors): widening every bit pattern and rounding f32 values around
+    every 16-bit rounding boundary must match numpy bit for bit."""
+    import jax.numpy as jnp
+
+    from repro.kernels import specs, tiles
+
+    spec = specs.BF16 if name == "bfloat16" else specs.F16
+    bits = np.arange(1 << 16, dtype=np.uint32)
+    vals = bits.astype(np.uint16).view(spec.np_dtype)
+    finite = np.isfinite(vals.astype(np.float32))
+    wide = np.asarray(tiles.to_compute(spec, jnp.asarray(bits)))
+    np.testing.assert_array_equal(
+        wide[finite].view(np.uint32), vals[finite].astype(np.float32).view(np.uint32)
+    )
+    # f32 inputs: every finite 16-bit value, its f32 neighbours, the exact
+    # midpoints to the next value (ties to even), plus random f32 bits
+    v32 = vals[finite].astype(np.float32)
+    with np.errstate(over="ignore"):     # the largest value steps to inf
+        nxt = np.nextafter(vals[finite], np.asarray(np.inf, spec.np_dtype))
+    nxt = nxt.astype(np.float32)
+    mid = ((v32.astype(np.float64) + nxt.astype(np.float64)) / 2).astype(np.float32)
+    rnd = np.random.default_rng(0).integers(0, 1 << 32, 1 << 16, dtype=np.uint64)
+    x = np.concatenate([
+        v32, np.nextafter(v32, np.float32(np.inf)), np.nextafter(v32, np.float32(-np.inf)),
+        mid, rnd.astype(np.uint32).view(np.float32),
+    ])
+    x = x[np.isfinite(x)]
+    got = np.asarray(tiles.to_storage_bits(spec, jnp.asarray(x))).astype(np.uint16)
+    with np.errstate(over="ignore"):     # out-of-range values round to inf
+        want = x.astype(spec.np_dtype).view(np.uint16)
+    np.testing.assert_array_equal(got, want)

@@ -1,0 +1,10 @@
+"""device_idle.roi: share (%) of the time inside the ``bench.roi``
+annotations in which no operation ran on the device."""
+
+
+def read(ctx):
+    if ctx.trace is None:
+        return None
+    span = ctx.trace.span_seconds("roi")
+    busy = ctx.trace.busy_seconds("roi")
+    return None if busy is None or not span else 100.0 * (1.0 - busy / span)

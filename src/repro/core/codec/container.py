@@ -29,6 +29,7 @@ from typing import Iterator
 
 import numpy as np
 
+from repro import obs
 from repro.core.codec import plan as plan_mod
 from repro.core.codec.plan import Plan
 from repro.core.codec.transform import BlockEncoding, derive_layout
@@ -370,28 +371,26 @@ def build_frame(payload: bytes, seq: int, last: bool, *, raw: bool = False,
     its stage-off form and ``stage=...`` can never lose.  Stage-off frames
     are byte-identical to frames built before the stage existed.
     """
-    flags = (FLAG_LAST if last else 0) | (FLAG_RAW if raw else 0)
-    staged_code = 0
-    orig_payload = payload
-    if stage is not None and not raw:
-        from repro.core.codec import stage as stage_mod
+    with obs.span("container.build_frame", seq=seq):
+        flags = (FLAG_LAST if last else 0) | (FLAG_RAW if raw else 0)
+        staged_code = 0
+        orig_payload = payload
+        if stage is not None and not raw:
+            from repro.core.codec import stage as stage_mod
 
-        code = stage_mod.resolve(stage)
-        if code:
-            staged = stage_mod.stage_payload(payload, code)
-            if staged is not None:
-                payload = staged
-                flags |= code << FLAG_STAGE_SHIFT
-                staged_code = code
-    frame = FRAME_HEADER.pack(
-        FRAME_MAGIC, FRAME_VERSION, flags, seq, len(payload)
-    ) + payload
-    if not raw:
-        from repro import obs
-
-        if obs.enabled():
+            code = stage_mod.resolve(stage)
+            if code:
+                staged = stage_mod.stage_payload(payload, code)
+                if staged is not None:
+                    payload = staged
+                    flags |= code << FLAG_STAGE_SHIFT
+                    staged_code = code
+        frame = FRAME_HEADER.pack(
+            FRAME_MAGIC, FRAME_VERSION, flags, seq, len(payload)
+        ) + payload
+        if not raw and obs.enabled():
             obs.stream_stats.record_frame_built(
-                orig_payload, len(frame), seq, staged_code
+                orig_payload, len(frame), staged_code
             )
     return frame
 
@@ -582,7 +581,8 @@ def _iter_frames_iterable(source) -> Iterator[tuple[bytes, int]]:
     for frame in source:
         if saw_last:
             raise ValueError("SZx frame after the LAST-flagged frame")
-        payload, flags = _parse_one_frame(frame, seq_expected)
+        with obs.span("container.parse_frame", seq=seq_expected):
+            payload, flags = _parse_one_frame(frame, seq_expected)
         saw_last = bool(flags & FLAG_LAST)
         seq_expected += 1
         yield payload, flags

@@ -98,42 +98,49 @@ def _assemble_body(spec: DtypeSpec, mu, const, reqlen, nbytes, planes, L):
 
     Pure jnp -- runs inside the fused encode program.  The buffer is sized to
     the static worst case (``cap``); ``total`` is the actual body length.
-    Section layouts mirror ``container.build_stream`` byte for byte.
+    Section layouts mirror ``container.build_stream`` byte for byte.  The
+    caller runs it under the name scope ``szx_assemble``, each section under
+    its own (``bitmap``, ``mu``, ``reqlen``, ``lcodes``, ``mid_plane<k>``),
+    so the profiler's trace names the device ops.
     """
     nb, bs = L.shape
     W = spec.itemsize
     nbm = (nb + 7) // 8
     mu_off = nbm
     req_off = nbm + W * nb
-    cap = req_off + nb + (nb * bs + 3) // 4 + nb * bs * W
+    cap = _capacity(nb, bs, W)
     idt = jnp.int32        # caller guarantees cap fits (host fallback otherwise)
 
     body = jnp.zeros((cap,), jnp.uint8)
-    # const bitmap (np.packbits order: MSB-first within each byte)
-    cpad = jnp.pad(const.astype(jnp.int32), (0, nbm * 8 - nb))
-    bitmap = (cpad.reshape(nbm, 8) << jnp.arange(7, -1, -1)).sum(axis=1)
-    body = body.at[:nbm].set(bitmap.astype(jnp.uint8))
-    # mu words, little-endian bytes (same order as the host .view(np.uint8))
-    body = body.at[mu_off:req_off].set(
-        jax.lax.bitcast_convert_type(mu, jnp.uint8).reshape(-1)
-    )
-    # compacted reqlen: rank = position among non-constant blocks; constant
-    # blocks scatter to `cap`, which mode="drop" discards
-    nonconst = ~const
-    incl = jnp.cumsum(nonconst.astype(idt))
-    nnc = incl[-1]
-    rank = incl - 1
-    dst = jnp.where(nonconst, req_off + rank, cap)
-    body = body.at[dst].set(reqlen.astype(jnp.uint8), mode="drop")
-    # 2-bit L codes, 4 per byte little-endian: byte = c0|c1<<2|c2<<4|c3<<6.
-    # Contributions hit disjoint bit positions of a zeroed buffer, so
-    # scatter-add composes them exactly like the host pack_2bit.
-    l_off = req_off + nnc
-    pos = rank[:, None] * bs + jnp.arange(bs, dtype=idt)[None, :]
-    contrib = (L << ((pos % 4) * 2).astype(jnp.int32)).astype(jnp.uint8)
-    ldst = jnp.where(nonconst[:, None], l_off + pos // 4, cap)
-    body = body.at[ldst.reshape(-1)].add(contrib.reshape(-1), mode="drop")
-    nl = (nnc * bs + 3) // 4
+    with jax.named_scope("bitmap"):
+        # const bitmap (np.packbits order: MSB-first within each byte)
+        cpad = jnp.pad(const.astype(jnp.int32), (0, nbm * 8 - nb))
+        bitmap = (cpad.reshape(nbm, 8) << jnp.arange(7, -1, -1)).sum(axis=1)
+        body = body.at[:nbm].set(bitmap.astype(jnp.uint8))
+    with jax.named_scope("mu"):
+        # mu words, little-endian bytes (same order as the host .view(np.uint8))
+        body = body.at[mu_off:req_off].set(
+            jax.lax.bitcast_convert_type(mu, jnp.uint8).reshape(-1)
+        )
+    with jax.named_scope("reqlen"):
+        # compacted reqlen: rank = position among non-constant blocks;
+        # constant blocks scatter to `cap`, which mode="drop" discards
+        nonconst = ~const
+        incl = jnp.cumsum(nonconst.astype(idt))
+        nnc = incl[-1]
+        rank = incl - 1
+        dst = jnp.where(nonconst, req_off + rank, cap)
+        body = body.at[dst].set(reqlen.astype(jnp.uint8), mode="drop")
+    with jax.named_scope("lcodes"):
+        # 2-bit L codes, 4 per byte little-endian: byte = c0|c1<<2|c2<<4|c3<<6.
+        # Contributions hit disjoint bit positions of a zeroed buffer, so
+        # scatter-add composes them exactly like the host pack_2bit.
+        l_off = req_off + nnc
+        pos = rank[:, None] * bs + jnp.arange(bs, dtype=idt)[None, :]
+        contrib = (L << ((pos % 4) * 2).astype(jnp.int32)).astype(jnp.uint8)
+        ldst = jnp.where(nonconst[:, None], l_off + pos // 4, cap)
+        body = body.at[ldst.reshape(-1)].add(contrib.reshape(-1), mode="drop")
+        nl = (nnc * bs + 3) // 4
     # mid stream in (block, value, byteplane) order: value v stores bytes
     # L[v] .. nbytes[v]-1 of its plane column at offset start[v] (the
     # exclusive prefix sum of the per-value counts `nbytes - L`)
@@ -143,10 +150,11 @@ def _assemble_body(spec: DtypeSpec, mu, const, reqlen, nbytes, planes, L):
     start = ends - counts
     nmid = ends[-1]
     for k in range(W):
-        plane = jnp.clip(L + k, 0, W - 1)[:, None, :]
-        byte = jnp.take_along_axis(planes, plane, axis=1).reshape(-1)
-        mdst = jnp.where(counts > k, mid_off + start + k, cap)
-        body = body.at[mdst].set(byte, mode="drop")
+        with jax.named_scope(f"mid_plane{k}"):
+            plane = jnp.clip(L + k, 0, W - 1)[:, None, :]
+            byte = jnp.take_along_axis(planes, plane, axis=1).reshape(-1)
+            mdst = jnp.where(counts > k, mid_off + start + k, cap)
+            body = body.at[mdst].set(byte, mode="drop")
     return body, mid_off + nmid, nnc, nmid
 
 
@@ -157,12 +165,14 @@ def _encode_device_jit(xb, e, p_e, *, spec: DtypeSpec, backend: str):
     mu, const, reqlen, _shift, nbytes, planes, L = ops.encode_staged(
         xb, e, p_e, spec=spec, backend=backend
     )
-    return _assemble_body(spec, mu, const, reqlen, nbytes, planes, L)
+    with jax.named_scope("szx_assemble"):
+        return _assemble_body(spec, mu, const, reqlen, nbytes, planes, L)
 
 
-def _body_cap(p: Plan) -> int:
-    nb, bs, W = p.nblocks, p.block_size, p.dtype.itemsize
-    return (nb + 7) // 8 + W * nb + nb + (nb * bs + 3) // 4 + nb * bs * W
+def _capacity(nb: int, bs: int, itemsize: int) -> int:
+    """Worst-case body length of ``nb`` blocks of ``bs`` values: bitmap, mu
+    words, reqlen and L codes of every block, every value's bytes in full."""
+    return (nb + 7) // 8 + itemsize * nb + nb + (nb * bs + 3) // 4 + nb * bs * itemsize
 
 
 # ---------------------------------------------------------------------------
@@ -181,14 +191,16 @@ def encode_device(xb, p: Plan) -> DeviceEncoding:
     from repro.kernels import ops
 
     backend = ops._resolve(p.backend, p.dtype)
-    if backend == "numpy" or p.nblocks == 0 or _body_cap(p) > _INT32_SAFE:
+    cap = _capacity(p.nblocks, p.block_size, p.dtype.itemsize)
+    if backend == "numpy" or p.nblocks == 0 or cap > _INT32_SAFE:
         return _encode_host(xb, p)
     spec = p.dtype
     from repro.kernels import specs
 
     p_e = specs.exact_exponent_of(float(p.error_bound))
-    with ops._x64_scope(spec):        # f64 words need x64 for asarray AND trace
-        # jnp.asarray handles numpy AND already-device inputs -- no host bounce
+    with ops._x64_scope(spec), obs.span("device.encode.dispatch"):
+        # f64 words need x64 for asarray AND trace; jnp.asarray handles
+        # numpy AND already-device inputs -- no host bounce
         body, total, nnc, nmid = _encode_device_jit(
             jnp.asarray(xb, spec.np_dtype),
             jnp.asarray(p.error_bound, spec.compute_np_dtype),
@@ -226,19 +238,21 @@ def to_stream(enc: DeviceEncoding) -> bytes:
     if enc.kind != "szx-v2":
         raise ValueError(f"cannot serialize encoding kind {enc.kind!r}")
     p: Plan = enc.info["plan"]
-    body, total, nnc, nmid = jax.device_get(
-        (enc["body"], enc["total"], enc["nnc"], enc["nmid"])
-    )
+    with obs.span("device.encode.get"):
+        body, total, nnc, nmid = jax.device_get(
+            (enc["body"], enc["total"], enc["nnc"], enc["nmid"])
+        )
     if obs.enabled():
         obs.counter("device.get.calls", op="encode_stream").inc()
         obs.counter("device.get.bytes", op="encode_stream").inc(
             int(np.asarray(body).nbytes)
         )
-    header = container.HEADER.pack(
-        container.MAGIC, container.VERSION, p.dtype.code, p.block_size, p.n,
-        p.error_bound, p.nblocks, int(nnc), int(nmid),
-    )
-    return header + body[: int(total)].tobytes()
+    with obs.span("container.serialize"):
+        header = container.HEADER.pack(
+            container.MAGIC, container.VERSION, p.dtype.code, p.block_size,
+            p.n, p.error_bound, p.nblocks, int(nnc), int(nmid),
+        )
+        return header + body[: int(total)].tobytes()
 
 
 def encode_to_stream(xb, p: Plan) -> bytes:
@@ -263,14 +277,19 @@ def _decode_device_jit(body, nnc, lo, *, spec: DtypeSpec, backend: str,
     )
 
 
-def _bucket_body(raw: np.ndarray, cap: int) -> np.ndarray:
-    """Zero-pad the body bytes to the next power-of-2 bucket (bounded by the
-    worst-case capacity) so each chunk geometry compiles a handful of decode
-    programs instead of one per payload length.  Gathers in the decode
-    program are index-clipped, so the padding is never observable."""
-    size = min(cap, 1 << (max(int(raw.size), 1) - 1).bit_length())
+def _bucket_body(parts, cap: int) -> np.ndarray:
+    """The body bytes ``parts`` laid end to end, zero-padded to the next
+    power-of-2 bucket (bounded by the worst-case capacity) so each chunk
+    geometry compiles a handful of decode programs instead of one per
+    payload length.  Gathers in the decode program are index-clipped, so the
+    padding is never observable."""
+    n = sum(int(p.size) for p in parts)
+    size = min(cap, 1 << (max(n, 1) - 1).bit_length())
     padded = np.zeros(size, np.uint8)
-    padded[: raw.size] = raw
+    off = 0
+    for p in parts:
+        padded[off: off + p.size] = p
+        off += p.size
     return padded
 
 
@@ -336,7 +355,8 @@ def decode_stream(buf, *, backend: str = "auto", out=None, block_range=None):
     """
     from repro.kernels import ops
 
-    spec, bs, n, nb, nnc, nmid, prefix_len = _checked_stream_header(buf)
+    with obs.span("container.parse_header"):
+        spec, bs, n, nb, nnc, nmid, prefix_len = _checked_stream_header(buf)
     backend = ops._resolve(backend, spec)
     if backend == "numpy":
         return None
@@ -345,10 +365,7 @@ def decode_stream(buf, *, backend: str = "auto", out=None, block_range=None):
         raise ValueError(
             f"truncated SZx stream ({len(buf)} bytes, expected {expected})"
         )
-    cap = nb and (
-        (nb + 7) // 8 + spec.itemsize * nb + nb + (nb * bs + 3) // 4
-        + nb * bs * spec.itemsize
-    )
+    cap = _capacity(nb, bs, spec.itemsize)
     blen = expected - container.HEADER.size
     if nb == 0 or cap > _INT32_SAFE or blen > cap:
         return None
@@ -356,26 +373,14 @@ def decode_stream(buf, *, backend: str = "auto", out=None, block_range=None):
     if not 0 <= lo < hi <= nb:
         return None                      # host path raises the canonical error
     raw = np.frombuffer(buf, np.uint8, blen, container.HEADER.size)
-    with ops._x64_scope(spec):
-        dev_body = jax.device_put(_bucket_body(raw, cap))
-        vals, meas = _decode_device_jit(
-            dev_body, np.int32(nnc), np.int32(lo),
-            spec=spec, backend=backend, nb=nb, bs=bs, rb=hi - lo, rebase=False,
-        )
-        vals, meas = jax.device_get((vals, meas))
-    if obs.enabled():
-        obs.counter("device.put.calls", op="decode_stream").inc()
-        obs.counter("device.put.bytes", op="decode_stream").inc(
-            int(dev_body.nbytes)
-        )
-        obs.counter("device.get.calls", op="decode_stream").inc()
-        obs.counter("device.get.bytes", op="decode_stream").inc(
-            int(vals.nbytes) + int(np.asarray(meas).nbytes)
-        )
-    _check_measured(meas, nnc, nmid, spec)
-    flat = vals.reshape(-1)[: min(hi * bs, n) - lo * bs]
+    vals = _decode_body(
+        [raw], cap, spec=spec, backend=backend, nb=nb, bs=bs, nnc=nnc,
+        nmid=nmid, lo=lo, hi=hi, rebase=False, op="decode_stream",
+    )
+    flat = vals[: min(hi * bs, n) - lo * bs]
     if out is not None:
-        np.copyto(out, flat)
+        with obs.span("device.decode.copy_out"):
+            np.copyto(out, flat)
         return out
     return flat
 
@@ -394,37 +399,55 @@ def decode_range(prefix: bytes, mid: bytes, lo: int, hi: int, *,
     """
     from repro.kernels import ops
 
-    spec, bs, n, nb, nnc, nmid, prefix_len = _checked_stream_header(prefix)
+    with obs.span("container.parse_header"):
+        spec, bs, n, nb, nnc, nmid, prefix_len = _checked_stream_header(prefix)
     backend = ops._resolve(backend, spec)
     if backend == "numpy":
         return None
-    cap = nb and (
-        (nb + 7) // 8 + spec.itemsize * nb + nb + (nb * bs + 3) // 4
-        + nb * bs * spec.itemsize
-    )
+    cap = _capacity(nb, bs, spec.itemsize)
     if nb == 0 or cap > _INT32_SAFE or not 0 <= lo < hi <= nb:
         return None
-    raw = np.concatenate([
+    parts = [
         np.frombuffer(prefix, np.uint8, prefix_len - container.HEADER.size,
                       container.HEADER.size),
         np.frombuffer(mid, np.uint8),
-    ])
-    if raw.size > cap:
+    ]
+    if parts[0].size + parts[1].size > cap:
         return None
+    return _decode_body(
+        parts, cap, spec=spec, backend=backend, nb=nb, bs=bs, nnc=nnc,
+        nmid=nmid, lo=lo, hi=hi, rebase=True, op="decode_range",
+    )
+
+
+def _decode_body(parts, cap: int, *, spec: DtypeSpec, backend: str, nb: int,
+                 bs: int, nnc: int, nmid: int, lo: int, hi: int, rebase: bool,
+                 op: str) -> np.ndarray:
+    """Blocks [lo, hi) of one stream body on the device -> flat values.
+
+    Stages the body bytes ``parts`` into one bucketed buffer, puts it on the
+    device, runs the decode program and fetches the values with the
+    validation scalars: one put, one get, counted under ``op``."""
+    from repro.kernels import ops
+
+    with obs.span("device.decode.stage"):
+        body = _bucket_body(parts, cap)
     with ops._x64_scope(spec):
-        dev_body = jax.device_put(_bucket_body(raw, cap))
-        vals, meas = _decode_device_jit(
-            dev_body, np.int32(nnc), np.int32(lo),
-            spec=spec, backend=backend, nb=nb, bs=bs, rb=hi - lo, rebase=True,
-        )
-        vals, meas = jax.device_get((vals, meas))
+        with obs.span("device.decode.put"):
+            dev_body = jax.device_put(body)
+        with obs.span("device.decode.dispatch", lo=lo, hi=hi):
+            vals, meas = _decode_device_jit(
+                dev_body, np.int32(nnc), np.int32(lo),
+                spec=spec, backend=backend, nb=nb, bs=bs, rb=hi - lo,
+                rebase=rebase,
+            )
+        with obs.span("device.decode.get"):
+            vals, meas = jax.device_get((vals, meas))
     if obs.enabled():
-        obs.counter("device.put.calls", op="decode_range").inc()
-        obs.counter("device.put.bytes", op="decode_range").inc(
-            int(dev_body.nbytes)
-        )
-        obs.counter("device.get.calls", op="decode_range").inc()
-        obs.counter("device.get.bytes", op="decode_range").inc(
+        obs.counter("device.put.calls", op=op).inc()
+        obs.counter("device.put.bytes", op=op).inc(int(dev_body.nbytes))
+        obs.counter("device.get.calls", op=op).inc()
+        obs.counter("device.get.bytes", op=op).inc(
             int(vals.nbytes) + int(np.asarray(meas).nbytes)
         )
     _check_measured(meas, nnc, nmid, spec)

@@ -145,7 +145,8 @@ class SZxCodec:
     def _compress_planned(self, xt: np.ndarray, p: Plan) -> bytes:
         from repro.kernels import ops
 
-        xb = plan_mod.to_blocks(xt, p)
+        with obs.span("plan.to_blocks"):
+            xb = plan_mod.to_blocks(xt, p)
         if ops._resolve(p.backend, p.dtype) == "numpy":
             # host mirror: encode + serialize entirely in numpy (byte-identical
             # to the device path; this is also the benchmark hot path)
@@ -283,7 +284,8 @@ class SZxCodec:
         if dtype is not None:
             x = x.astype(np.dtype(dtype), copy=False)
         spec = plan_mod.spec_for(x.dtype)
-        e = plan_mod.resolve_error_bound(x, b, spec=spec)
+        with obs.span("plan.resolve_error_bound"):
+            e = plan_mod.resolve_error_bound(x, b, spec=spec)
         flat = x.reshape(-1)
         per_chunk = plan_mod.chunk_elements(self.block_size, chunk_bytes, spec.itemsize)
         nchunks = max((flat.size + per_chunk - 1) // per_chunk, 1)
@@ -353,7 +355,8 @@ class SZxCodec:
                         )
                 elif payload[5] != spec_code:
                     raise ValueError("SZx frame sequence mixes dtypes")
-                _code, fn, _e = container.peek_stream_meta(payload)
+                with obs.span("container.parse_header"):
+                    _code, fn, _e = container.peek_stream_meta(payload)
                 if out is not None and off + fn > n:
                     raise ValueError(
                         f"SZx frame sequence longer than expected ({n} elements)"

@@ -451,9 +451,10 @@ def decode_staged(body, nnc, lo=0, *, spec: DtypeSpec = specs.F32, nb: int,
     _const, mu, shift, nbytes, rank, nnc_seen = ref.parse_body_ref(
         body, nnc, spec, nb
     )
-    words, L, mid_total = ref.gather_words_ref(
-        body, nnc, lo, nbytes, rank, spec, bs=bs, rb=rb, rebase=rebase
-    )
+    with jax.named_scope("szx_gather"):      # names its ops in the profiler
+        words, L, mid_total = ref.gather_words_ref(
+            body, nnc, lo, nbytes, rank, spec, bs=bs, rb=rb, rebase=rebase
+        )
     args = [jax.lax.dynamic_slice_in_dim(a, lo, rb, axis=0)
             for a in (mu, shift, nbytes)]
     if backend == "kernel":

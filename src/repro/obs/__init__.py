@@ -2,9 +2,10 @@
 
 One global :class:`Registry` of counters / gauges / fixed-bucket histograms,
 a ``span(name, **attrs)`` context manager with monotonic timing and
-nesting, a bounded per-frame codec stream-stats log, and three exporters
-(Prometheus text, Chrome ``trace_event`` JSON, human summary table).  See
-docs/OBSERVABILITY.md.
+nesting, and three exporters (Prometheus text, Chrome ``trace_event`` JSON,
+human summary table).  While a ``jax.profiler`` trace is recording, each
+span also opens a ``jax.profiler.TraceAnnotation`` of its name, so it lands
+in the profiler's trace on the device's clock.  See docs/OBSERVABILITY.md.
 
 Telemetry is OFF by default and costs nearly nothing while off: every
 instrumented hot path checks :func:`enabled` -- a module-level flag read --
@@ -27,6 +28,7 @@ from __future__ import annotations
 
 import functools
 import os
+import sys
 import threading
 import time
 
@@ -69,7 +71,7 @@ def disable() -> None:
 
 
 def reset() -> None:
-    """Clear every metric, span, and frame record in the global registry."""
+    """Clear every metric and span in the global registry."""
     REGISTRY.reset()
 
 
@@ -89,10 +91,31 @@ def _depth() -> int:
     return getattr(_local, "depth", 0)
 
 
-class _Span:
-    """Live span: times with ``perf_counter_ns``, records on exit."""
+_annotation_cls = None
 
-    __slots__ = ("name", "attrs", "_t0")
+
+def _annotation(name: str, attrs: dict | None):
+    """A ``jax.profiler.TraceAnnotation`` for a span, or None where no
+    profiler trace records: JAX not imported (obs never imports it), or no
+    trace active."""
+    global _annotation_cls
+    cls = _annotation_cls
+    if cls is None:
+        if "jax" not in sys.modules:
+            return None
+        from jax.profiler import TraceAnnotation as cls
+
+        _annotation_cls = cls
+    if not cls.is_enabled():
+        return None
+    return cls(name, **attrs) if attrs else cls(name)
+
+
+class _Span:
+    """Live span: times with ``perf_counter_ns``, records on exit, and
+    mirrors itself into the profiler's trace while one records."""
+
+    __slots__ = ("name", "attrs", "_t0", "_ann")
 
     def __init__(self, name: str, attrs: dict | None):
         self.name = name
@@ -100,11 +123,17 @@ class _Span:
 
     def __enter__(self):
         _local.depth = _depth() + 1
+        self._ann = _annotation(self.name, self.attrs)
+        if self._ann is not None:
+            self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         dur = time.perf_counter_ns() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+            self._ann = None
         depth = _depth()
         _local.depth = depth - 1
         REGISTRY.record_span(
@@ -159,7 +188,9 @@ def span(name: str, **attrs):
     When telemetry is disabled this returns a shared no-op object (the
     enabled flag is checked before any allocation).  When enabled, the span
     records (name, start, duration, thread, nesting depth, attrs) into the
-    registry's span log on exit.
+    registry's span log on exit, and, while a ``jax.profiler`` trace is
+    recording, spans a ``TraceAnnotation`` of the same name and attrs on the
+    calling thread.
     """
     if not _ENABLED:
         return _NULL

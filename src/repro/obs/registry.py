@@ -1,10 +1,10 @@
 """Thread-safe metric registry: counters, gauges, fixed-bucket histograms,
-plus the bounded span log and codec frame log.
+plus the bounded span log.
 
 Everything here is stdlib-only and allocation-light: one small lock per
 metric (so concurrent observers never contend on a global lock for the
-increment itself), one registry-level lock for metric creation and the two
-bounded logs.  The registry never samples the clock -- callers time with
+increment itself), one registry-level lock for metric creation and the
+bounded span log.  The registry never samples the clock -- callers time with
 ``time.perf_counter_ns`` and hand finished durations in -- so a
 :class:`Registry` is equally usable from tests, the serve tier, and the
 codec hot paths.
@@ -118,18 +118,17 @@ class Histogram:
 
 
 class Registry:
-    """Thread-safe home for metrics, the span log, and the codec frame log.
+    """Thread-safe home for metrics and the span log.
 
-    The two logs are bounded deques (oldest entries drop); aggregate span
+    The span log is a bounded deque (oldest entries drop); aggregate span
     timings survive the bound in ``span_aggregates`` so long runs still
     export correct totals.
     """
 
-    def __init__(self, *, max_spans: int = 16384, max_frames: int = 4096):
+    def __init__(self, *, max_spans: int = 16384):
         self._lock = threading.Lock()
         self._metrics: dict[tuple, object] = {}
         self._spans: deque = deque(maxlen=max_spans)
-        self._frames: deque = deque(maxlen=max_frames)
         self._span_agg: dict[str, list] = {}
 
     # ------------------------------------------------------------- metrics
@@ -160,7 +159,7 @@ class Registry:
         with self._lock:
             return list(self._metrics.values())
 
-    # ---------------------------------------------------------------- logs
+    # ------------------------------------------------------------ span log
     def record_span(self, name: str, t0_ns: int, dur_ns: int, tid: int,
                     depth: int, attrs: dict | None) -> None:
         with self._lock:
@@ -180,14 +179,6 @@ class Registry:
         """name -> (count, total_ns); survives the span-log bound."""
         with self._lock:
             return {k: (v[0], v[1]) for k, v in self._span_agg.items()}
-
-    def record_frame(self, rec: dict) -> None:
-        with self._lock:
-            self._frames.append(rec)
-
-    def frames(self) -> list[dict]:
-        with self._lock:
-            return list(self._frames)
 
     # ------------------------------------------------------------ lifecycle
     def snapshot(self) -> dict:
@@ -215,5 +206,4 @@ class Registry:
         with self._lock:
             self._metrics.clear()
             self._spans.clear()
-            self._frames.clear()
             self._span_agg.clear()

@@ -10,9 +10,10 @@ Two halves:
   stage-on and stage-off frames without destaging anything.
 
 * Runtime recorders -- ``record_*`` helpers called from the codec hot paths
-  when :func:`repro.obs.enabled`.  They feed the global registry's counters/
-  histograms and the bounded frame log that ``python -m repro.core.codec
-  info --stats`` and ``/v1/metrics`` surface.
+  when :func:`repro.obs.enabled`.  They feed the global registry's counters
+  and histograms, which ``/v1/metrics`` and the Prometheus exporter surface.
+  ``python -m repro.core.codec info --stats`` parses frames with
+  :func:`frame_stats` instead.
 
 Container imports are deferred into the functions: ``repro.core.codec``
 modules import :mod:`repro.obs` at module scope, and this keeps the obs
@@ -44,12 +45,12 @@ def _l2bit_table() -> np.ndarray:
 def _l2bit_hist(lbytes: np.ndarray) -> np.ndarray:
     """Exact per-code counts of the packed 2-bit fields in ``lbytes``.
 
-    This sits on the telemetry-on compress hot path (once per frame), so it
-    counts via popcount identities over a uint64 view -- for each 2-bit
-    field f: popcount(f) = [f==1] + [f==2] + 2*[f==3], the high bits alone
-    give c2+c3, and low&high gives c3 -- which is ~3x faster than a
-    256-bin bincount.  Falls back to the byte-table bincount on numpy < 2
-    (no ``bitwise_count``)."""
+    ``info --stats`` runs it once per frame, so it counts via popcount
+    identities over a uint64 view -- for each 2-bit field f: popcount(f) =
+    [f==1] + [f==2] + 2*[f==3], the high bits alone give c2+c3, and
+    low&high gives c3 -- which is ~3x faster than a 256-bin bincount.
+    Falls back to the byte-table bincount on numpy < 2 (no
+    ``bitwise_count``)."""
     if not hasattr(np, "bitwise_count"):
         hist = _l2bit_table().T @ np.bincount(lbytes, minlength=256)
         return hist
@@ -158,10 +159,8 @@ def frame_stats(frame: bytes) -> dict:
 # ---------------------------------------------------------------------------
 
 def record_compress(payload, seconds: float) -> None:
-    """One SZxCodec.compress call -> counters + encode-time histogram.
-
-    Header-only stats (no L bincount): the per-frame log, fed once per frame
-    by :func:`record_frame_built`, carries the histogram."""
+    """One SZxCodec.compress call -> counters + encode-time histogram,
+    from header-only stats (no L-code histogram)."""
     from repro import obs
 
     st = payload_stats(payload, l_hist=False)
@@ -185,24 +184,13 @@ def record_decompress(nbytes_out: int, seconds: float,
     r.histogram("codec.decompress.seconds", kind=kind).observe(seconds)
 
 
-def record_frame_built(payload, frame_len: int, seq: int,
-                       stage_code: int) -> None:
-    """One container frame built -> per-frame record in the frame log."""
+def record_frame_built(payload, frame_len: int, stage_code: int) -> None:
+    """One container frame built -> the ``codec.frames.*`` counters, from
+    the payload's header alone."""
     from repro import obs
-    from repro.core.codec import container
 
-    rec = payload_stats(payload)
-    staged_mid = frame_len - container.FRAME_HEADER.size - rec["prefix_bytes"]
-    rec.update({
-        "seq": int(seq),
-        "stage": int(stage_code),
-        "frame_bytes": int(frame_len),
-        "raw_mid_bytes": rec["mid_bytes"],
-        "staged_mid_bytes": staged_mid if stage_code else rec["mid_bytes"],
-        "ratio": rec["raw_bytes"] / frame_len if frame_len else 0.0,
-    })
+    st = payload_stats(payload, l_hist=False)
     r = obs.REGISTRY
-    r.record_frame(rec)
     r.counter("codec.frames.built", stage=stage_code).inc()
-    r.counter("codec.frames.raw_bytes").inc(rec["raw_bytes"])
+    r.counter("codec.frames.raw_bytes").inc(st["raw_bytes"])
     r.counter("codec.frames.frame_bytes").inc(frame_len)

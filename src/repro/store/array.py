@@ -213,6 +213,7 @@ class ArrayStore:
         return man
 
     @staticmethod
+    @obs.traced("store.open")
     def open(
         path_or_file, *, backend: str = "numpy", device: bool = False,
         cache=None, cache_ns: str | None = None,
@@ -474,14 +475,15 @@ class CompressedArray:
             lo_b, hi_b = grid_mod.block_range_for_box(local, cdims, bs)
             seg = self._decode_chunk_range(cid, lo_b, hi_b)
             out_sl = tuple(slice(lo, hi) for lo, hi in outr)
-            if all(hi - lo == d for (lo, hi), d in zip(local, cdims)):
-                # whole chunk requested: the segment IS the chunk, C order
-                out[out_sl] = seg.reshape(cdims)
-            else:
-                idx = np.ravel_multi_index(
-                    np.ix_(*[np.arange(lo, hi) for lo, hi in local]), cdims
-                ) - lo_b * bs
-                out[out_sl] = seg[idx]
+            with obs.span("store.assemble", chunk=cid):
+                if all(hi - lo == d for (lo, hi), d in zip(local, cdims)):
+                    # whole chunk requested: the segment IS the chunk, C order
+                    out[out_sl] = seg.reshape(cdims)
+                else:
+                    idx = np.ravel_multi_index(
+                        np.ix_(*[np.arange(lo, hi) for lo, hi in local]), cdims
+                    ) - lo_b * bs
+                    out[out_sl] = seg[idx]
         return out.reshape(roi.out_shape)
 
     def read(self, key=Ellipsis) -> np.ndarray:
@@ -517,18 +519,20 @@ class CompressedArray:
                                      hi_b: int) -> np.ndarray:
         off, length, elements = (int(v) for v in self._frames[cid])
         f = self._src(cid)
-        _flags, plen, sheader = container.read_frame_stream_header_at(
-            f, off, cid + self._seq_base
-        )
-        if container.FRAME_HEADER.size + plen != length:
-            raise ValueError("corrupt store index (frame length mismatch)")
-        prefix_len = container.stream_prefix_length(sheader)
-        if prefix_len > plen:
-            raise ValueError("truncated SZx stream (metadata exceeds payload)")
-        rest = container._read_exact(f, prefix_len - container.HEADER.size)
-        sec = container.parse_stream_sections(
-            sheader + rest, backend=self._backend
-        )
+        with obs.span("store.chunk.read_prefix", chunk=cid):
+            _flags, plen, sheader = container.read_frame_stream_header_at(
+                f, off, cid + self._seq_base
+            )
+            if container.FRAME_HEADER.size + plen != length:
+                raise ValueError("corrupt store index (frame length mismatch)")
+            prefix_len = container.stream_prefix_length(sheader)
+            if prefix_len > plen:
+                raise ValueError(
+                    "truncated SZx stream (metadata exceeds payload)")
+            rest = container._read_exact(f, prefix_len - container.HEADER.size)
+            sec = container.parse_stream_sections(
+                sheader + rest, backend=self._backend
+            )
         if sec.plan.n != elements:
             raise ValueError(
                 f"corrupt store index (chunk {cid}: stream has {sec.plan.n} "
@@ -539,21 +543,22 @@ class CompressedArray:
         mid = b""
         if mhi > mlo:
             stage_code = container.stage_of_flags(_flags)
-            if stage_code:
-                # staged frame: read the stage table + only the segment
-                # records covering [lo_b, hi_b) and destage them -- bytes
-                # read stay proportional to the ROI, like the raw path
-                from repro.core.codec import stage as stage_mod
+            with obs.span("store.chunk.read_mid", chunk=cid):
+                if stage_code:
+                    # staged frame: read the stage table + only the segment
+                    # records covering [lo_b, hi_b) and destage them -- bytes
+                    # read stay proportional to the ROI, like the raw path
+                    from repro.core.codec import stage as stage_mod
 
-                mid = stage_mod.read_mid_range(
-                    f, off + container.FRAME_HEADER.size + prefix_len,
-                    sec, stage_code, lo_b, hi_b,
-                )
-            else:
-                f.seek(off + container.FRAME_HEADER.size + prefix_len + mlo)
-                mid = container._read_exact(f, mhi - mlo)
-                if obs.enabled():
-                    obs.counter("store.roi.mid_bytes_read").inc(mhi - mlo)
+                    mid = stage_mod.read_mid_range(
+                        f, off + container.FRAME_HEADER.size + prefix_len,
+                        sec, stage_code, lo_b, hi_b,
+                    )
+                else:
+                    f.seek(off + container.FRAME_HEADER.size + prefix_len + mlo)
+                    mid = container._read_exact(f, mhi - mlo)
+                    if obs.enabled():
+                        obs.counter("store.roi.mid_bytes_read").inc(mhi - mlo)
         if obs.enabled():
             # staged mid reads are counted (at actual on-disk size) by
             # stage.read_mid_range as codec.stage.roi_bytes_read

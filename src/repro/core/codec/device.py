@@ -10,9 +10,10 @@ size crossing the link, plus a host-side gather per frame.
 
 :func:`encode_device` stages the whole encode as ONE jitted program: the
 fused stats+pack kernel (``ops.encode_staged``) AND the layout derivation --
-the ``nbytes - L`` per-value byte counts, their exclusive-cumsum offsets, and
-the scatter of every section (const bitmap, mu words, compacted reqlen,
-2-bit L codes, mid-byte stream) into one contiguous ``uint8`` body buffer.
+the const bitmap and mu words at fixed offsets, then the compacted reqlen,
+2-bit L codes and mid-byte stream, each front-packed by an order-preserving
+compaction of log2(length) static shifts (:func:`_compact`, no scatter) and
+placed at its prefix-sum offset in one contiguous ``uint8`` body buffer.
 A chunk therefore reaches the host as ONE ``jax.device_get`` of final
 container bytes plus a tiny header struct (:func:`to_stream` -- the
 transfer-spy test in ``tests/test_device_encoding.py`` pins the single-get
@@ -90,71 +91,135 @@ class DeviceEncoding:
 
 
 # ---------------------------------------------------------------------------
-# on-device layout derivation (the container byte layout, as scatters)
+# on-device layout derivation (the container byte layout, by compaction)
 # ---------------------------------------------------------------------------
 
+def _left(a, d: int):
+    """``a`` moved left by ``d`` places in row-major order, zeros after: a
+    move of ``d // lanes`` rows and ``d % lanes`` lanes."""
+    q, r = divmod(d, a.shape[1])
+    a = jnp.pad(a[q:], ((0, q), (0, 0)))
+    if r:
+        below = jnp.pad(a[1:], ((0, 1), (0, 0)))
+        a = jnp.concatenate([a[:, r:], below[:, :r]], axis=1)
+    return a
+
+
+def _move(arrays, go, d: int):
+    """Move the elements marked ``go`` left by ``d``; the places they leave
+    and nothing enters become zero."""
+    come = _left(go, d)
+    return tuple(jnp.where(come, _left(a, d), jnp.where(go, 0, a)) for a in arrays)
+
+
+def _compact(payload, shift, valid):
+    """Front-pack the kept bytes of a 2-D ``payload``, in row-major order.
+
+    ``payload`` (uint8), ``shift`` and ``valid`` share one shape; element
+    ``i`` of the row-major order, if ``valid``, moves to ``i - shift``.  The
+    shifts are those of a boolean-mask compaction: they do not decrease along
+    the kept elements, and no two kept elements share a destination.  Step
+    ``s`` moves the elements whose shift has bit ``s`` set left by a static
+    ``2**s``, with slices, concatenations and selects: no scatter.  No step
+    makes two kept elements collide, since the gap in their shifts, taken
+    modulo ``2**s``, stays below their distance.  Returns the kept bytes
+    front-packed, zeros after.  Two dimensions keep the TPU's lanes dense.
+    """
+    steps = (payload.size - 1).bit_length()
+    # a byte rides in the low 8 bits of one uint32 word with the part of its
+    # shift still to go, once that part fits in the other 24; the first steps
+    # of an array longer than 2**24 carry the two apart
+    apart = max(0, steps - 24)
+    x = jnp.where(valid, payload, 0).astype(jnp.uint32)
+    sh = jnp.where(valid, shift, 0).astype(jnp.uint32)  # empty: zero; it stays
+    for s in range(apart):
+        x, sh = _move((x, sh), (sh & 1) == 1, 1 << s)
+        sh = sh >> 1
+    w = sh << 8 | x
+    for s in range(apart, steps):
+        (w,) = _move((w,), (w & 256) != 0, 1 << s)
+        w = w >> 9 << 8 | w & 255
+    return (w & 255).astype(payload.dtype)
+
+
 def _assemble_body(spec: DtypeSpec, mu, const, reqlen, nbytes, planes, L):
-    """Scatter every v2 section into one contiguous uint8 body buffer.
+    """Lay out every v2 section in one contiguous uint8 body buffer.
 
     Pure jnp -- runs inside the fused encode program.  The buffer is sized to
     the static worst case (``cap``); ``total`` is the actual body length.
     Section layouts mirror ``container.build_stream`` byte for byte.  The
+    compacted sections (reqlen, L codes, mid bytes) are each one
+    :func:`_compact` of their candidates, placed at their data-dependent
+    offset by ``dynamic_update_slice``; the program holds no scatter.  The
     caller runs it under the name scope ``szx_assemble``, each section under
-    its own (``bitmap``, ``mu``, ``reqlen``, ``lcodes``, ``mid_plane<k>``),
-    so the profiler's trace names the device ops.
+    its own (``bitmap``, ``mu``, ``compact_reqlen``, ``compact_lcodes``,
+    ``compact_mid``), so the profiler's trace names the device ops.
     """
     nb, bs = L.shape
     W = spec.itemsize
     nbm = (nb + 7) // 8
-    mu_off = nbm
     req_off = nbm + W * nb
     cap = _capacity(nb, bs, W)
     idt = jnp.int32        # caller guarantees cap fits (host fallback otherwise)
 
-    body = jnp.zeros((cap,), jnp.uint8)
     with jax.named_scope("bitmap"):
         # const bitmap (np.packbits order: MSB-first within each byte)
         cpad = jnp.pad(const.astype(jnp.int32), (0, nbm * 8 - nb))
         bitmap = (cpad.reshape(nbm, 8) << jnp.arange(7, -1, -1)).sum(axis=1)
-        body = body.at[:nbm].set(bitmap.astype(jnp.uint8))
     with jax.named_scope("mu"):
         # mu words, little-endian bytes (same order as the host .view(np.uint8))
-        body = body.at[mu_off:req_off].set(
-            jax.lax.bitcast_convert_type(mu, jnp.uint8).reshape(-1)
-        )
-    with jax.named_scope("reqlen"):
-        # compacted reqlen: rank = position among non-constant blocks;
-        # constant blocks scatter to `cap`, which mode="drop" discards
-        nonconst = ~const
-        incl = jnp.cumsum(nonconst.astype(idt))
-        nnc = incl[-1]
-        rank = incl - 1
-        dst = jnp.where(nonconst, req_off + rank, cap)
-        body = body.at[dst].set(reqlen.astype(jnp.uint8), mode="drop")
-    with jax.named_scope("lcodes"):
-        # 2-bit L codes, 4 per byte little-endian: byte = c0|c1<<2|c2<<4|c3<<6.
-        # Contributions hit disjoint bit positions of a zeroed buffer, so
-        # scatter-add composes them exactly like the host pack_2bit.
+        mu_bytes = jax.lax.bitcast_convert_type(mu, jnp.uint8).reshape(-1)
+    body = jnp.concatenate([
+        bitmap.astype(jnp.uint8), mu_bytes, jnp.zeros((cap - req_off,), jnp.uint8)
+    ])
+    # a non-constant block moves left by the number of constant blocks before it
+    nonconst = ~const
+    skip = jnp.cumsum(const.astype(idt)) - const.astype(idt)
+    nnc = nb - jnp.sum(const.astype(idt))
+    with jax.named_scope("compact_reqlen"):
+        req = _compact(*(a[None, :] for a in (reqlen.astype(jnp.uint8), skip, nonconst)))
+        body = jax.lax.dynamic_update_slice(body, req[0], (req_off,))
+    with jax.named_scope("compact_lcodes"):
+        # 2-bit L codes of the non-constant blocks, 4 per byte little-endian:
+        # byte = c0|c1<<2|c2<<4|c3<<6 (a block's codes may share a byte with
+        # the next block's when bs % 4 != 0)
         l_off = req_off + nnc
-        pos = rank[:, None] * bs + jnp.arange(bs, dtype=idt)[None, :]
-        contrib = (L << ((pos % 4) * 2).astype(jnp.int32)).astype(jnp.uint8)
-        ldst = jnp.where(nonconst[:, None], l_off + pos // 4, cap)
-        body = body.at[ldst.reshape(-1)].add(contrib.reshape(-1), mode="drop")
+        codes = _compact(
+            L.astype(jnp.uint8),
+            jnp.broadcast_to(skip[:, None] * bs, L.shape),
+            jnp.broadcast_to(nonconst[:, None], L.shape),
+        ).reshape(-1)
+        nl_max = (codes.size + 3) // 4
+        c = jnp.pad(codes, (0, -codes.size % 512)).reshape(-1, 512)
+        # strided lanes of 512-wide rows: a stride-4 slice of the flat array
+        # is a gather on the TPU
+        packed = c[:, 0::4] | c[:, 1::4] << 2 | c[:, 2::4] << 4 | c[:, 3::4] << 6
+        body = jax.lax.dynamic_update_slice(
+            body, packed.reshape(-1)[:nl_max], (l_off,))
         nl = (nnc * bs + 3) // 4
-    # mid stream in (block, value, byteplane) order: value v stores bytes
-    # L[v] .. nbytes[v]-1 of its plane column at offset start[v] (the
-    # exclusive prefix sum of the per-value counts `nbytes - L`)
-    mid_off = l_off + nl
-    counts = jnp.maximum(nbytes[:, None] - L, 0).reshape(-1).astype(idt)
-    ends = jnp.cumsum(counts)
-    start = ends - counts
-    nmid = ends[-1]
-    for k in range(W):
-        with jax.named_scope(f"mid_plane{k}"):
-            plane = jnp.clip(L + k, 0, W - 1)[:, None, :]
-            byte = jnp.take_along_axis(planes, plane, axis=1).reshape(-1)
-            mdst = jnp.where(counts > k, mid_off + start + k, cap)
-            body = body.at[mdst].set(byte, mode="drop")
+    with jax.named_scope("compact_mid"):
+        # mid stream in (block, value, byteplane) order: value v stores bytes
+        # L[v] .. nbytes[v]-1 at offset start[v] (the exclusive prefix sum of
+        # the per-value counts).  Candidate (v, k) sits at v*W + k, so each
+        # kept byte of v moves left by v*W + L[v] - start[v].
+        mid_off = l_off + nl
+        counts = jnp.maximum(nbytes[:, None] - L, 0).reshape(-1).astype(idt)
+        ends = jnp.cumsum(counts)
+        start = ends - counts
+        nmid = ends[-1]
+        # (nb, bs*W) per block: the lane-dense layout in which the TPU
+        # interleaves planes without padding a minor axis of W
+        def by_value(a):
+            return jnp.swapaxes(a, 1, 2).reshape(nb, bs * W)
+
+        k = jnp.arange(W, dtype=idt)[None, :, None]
+        keep = (L[:, None, :] <= k) & (k < nbytes[:, None, None])
+        v = jnp.arange(nb * bs, dtype=idt).reshape(nb, 1, bs)
+        shift = v * W + L[:, None, :] - start.reshape(nb, 1, bs)
+        mid = _compact(by_value(planes), by_value(jnp.broadcast_to(shift, planes.shape)),
+                       by_value(keep)).reshape(-1)
+        # mid_off + nb*bs*W <= cap, so the offset is never clamped
+        body = jax.lax.dynamic_update_slice(body, mid, (mid_off,))
     return body, mid_off + nmid, nnc, nmid
 
 
@@ -184,7 +249,7 @@ def encode_device(xb, p: Plan) -> DeviceEncoding:
 
     On the 'jax'/'kernel' backends everything up to (and including) the final
     byte layout stays on device; the host backend ('numpy', or any input too
-    large for int32 scatter indices) produces the byte-identical numpy
+    large for int32 offsets) produces the byte-identical numpy
     mirror via the host serializer.  Arrays: ``body`` (worst-case-cap uint8
     buffer), ``total`` (actual body length), ``nnc``, ``nmid``.
     """

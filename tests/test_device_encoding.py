@@ -58,26 +58,94 @@ def test_chunked_encode_is_one_transfer_per_frame(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
+# the compaction primitive: equal to numpy boolean-mask compaction
+# ---------------------------------------------------------------------------
+
+def _mask(name, n, rng):
+    if name == "all":
+        return np.ones(n, bool)
+    if name == "none":
+        return np.zeros(n, bool)
+    if name == "alternating":
+        return np.arange(n) % 2 == 0
+    if name == "tail":
+        return (np.arange(n) >= n - 1000) & (rng.random(n) < 0.5)
+    return rng.random(n) < float(name)
+
+
+def _layouts(n):
+    """One row, one lane, and a grid of both where ``n`` factors."""
+    out = [(1, n), (n, 1)]
+    d = max(d for d in range(1, int(n**0.5) + 1) if n % d == 0)
+    if d > 1:
+        out.append((d, n // d))
+    return out
+
+
+@pytest.mark.parametrize("n,mask", [
+    (n, mask)
+    for n in (1, 2, 255, 256, 257, 5000)
+    for mask in ("all", "none", "alternating", "0.05", "0.5", "0.95")
+] + [(2**24 + 1024, "tail")])     # shifts past 2**24: byte and shift ride apart
+def test_compact_matches_boolean_mask(n, mask):
+    rng = np.random.default_rng(n)
+    payload = rng.integers(0, 256, n, dtype=np.uint8)
+    keep = _mask(mask, n, rng)
+    # a kept element moves left by the number of dropped ones before it; the
+    # shift of a dropped element is noise the primitive must ignore
+    shift = np.where(keep, np.arange(n) - np.cumsum(keep) + 1,
+                     rng.integers(0, n + 1, n)).astype(np.int32)
+    want = np.zeros(n, np.uint8)
+    want[: keep.sum()] = payload[keep]
+    for shape in _layouts(n) if n < 5001 else [(n // 1024, 1024)]:
+        got = device._compact(*(jnp.asarray(a.reshape(shape)) for a in (payload, shift, keep)))
+        np.testing.assert_array_equal(np.asarray(got).reshape(-1), want, err_msg=str(shape))
+
+
+# ---------------------------------------------------------------------------
 # byte identity: device assembly == host serializer, every dtype x backend
 # ---------------------------------------------------------------------------
 
+def _mixed(dtype, bs):
+    """Constant, verbatim and mid-heavy blocks in one frame, last block ragged."""
+    rng = np.random.default_rng(bs)
+    return np.concatenate([
+        np.full(3 * bs, 2.5),
+        _walk(2 * bs, seed=4, scale=0.01),
+        rng.uniform(-1e4, 1e4, 2 * bs),
+        np.full(bs + 3, -1.0),
+        _walk(3 * bs - 5, seed=6, scale=1.0),
+    ]).astype(dtype)
+
+
+@pytest.mark.parametrize("case", ["walks", "bs7", "bs33", "mixed"])
 @pytest.mark.parametrize("dtype", _DTYPES, ids=lambda d: np.dtype(d).name)
 @pytest.mark.parametrize("backend", ["jax", "kernel"])
-def test_device_stream_bit_identical_to_host(dtype, backend):
+def test_device_stream_bit_identical_to_host(dtype, backend, case):
     ref = SZxCodec(backend="numpy")
     dev = SZxCodec(backend=backend)
-    for n, bs, e in ((9999, 128, 1e-3), (257, 32, 1e-2), (1000, 128, 1.0)):
-        x = _walk(n, seed=n, dtype=dtype)
+    if case == "walks":
+        for n, bs, e in ((9999, 128, 1e-3), (257, 32, 1e-2), (1000, 128, 1.0)):
+            x = _walk(n, seed=n, dtype=dtype)
+            assert (
+                SZxCodec(block_size=bs, backend=backend).compress(x, e)
+                == SZxCodec(block_size=bs, backend="numpy").compress(x, e)
+            ), (np.dtype(dtype).name, backend, n, bs, e)
+        # constant + verbatim extremes
+        c = np.full(1500, 2.5).astype(dtype)
+        assert dev.compress(c, 1e-3) == ref.compress(c, 1e-3)
+        tiny = float(plan.finfo(np.dtype(dtype)).tiny)
+        v = _walk(2000, seed=3, dtype=dtype, scale=1.0)
+        assert dev.compress(v, tiny) == ref.compress(v, tiny)
+        return
+    # odd block sizes: a block's 2-bit L codes share a byte with the next's
+    bs = {"bs7": 7, "bs33": 33, "mixed": 128}[case]
+    x = _mixed(dtype, bs) if case == "mixed" else _walk(20 * bs + 3, seed=bs, dtype=dtype)
+    for e in (1e-3, 1e-1):
         assert (
             SZxCodec(block_size=bs, backend=backend).compress(x, e)
             == SZxCodec(block_size=bs, backend="numpy").compress(x, e)
-        ), (np.dtype(dtype).name, backend, n, bs, e)
-    # constant + verbatim extremes
-    c = np.full(1500, 2.5).astype(dtype)
-    assert dev.compress(c, 1e-3) == ref.compress(c, 1e-3)
-    tiny = float(plan.finfo(np.dtype(dtype)).tiny)
-    v = _walk(2000, seed=3, dtype=dtype, scale=1.0)
-    assert dev.compress(v, tiny) == ref.compress(v, tiny)
+        ), (np.dtype(dtype).name, backend, case, e)
 
 
 def test_encode_device_host_mirror_matches_device_record():

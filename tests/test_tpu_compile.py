@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.core.codec import device
 from repro.kernels import bitshuffle as kbit
 from repro.kernels import encode as kenc
 from repro.kernels import ops, specs, tiles
@@ -62,6 +63,7 @@ def compile_native(one_chip):
         args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
         text = jax.jit(fn).lower(*args).compile().as_text()
         assert "tpu_custom_call" in text, "no Pallas kernel in the program"
+        return text
 
     yield compile_
     jax.clear_caches()
@@ -81,6 +83,19 @@ def test_encode_compiles(compile_native, spec, nb):
         lambda x, e, pe: kenc.encode(x, e, pe, spec=spec),
         ((nb, 128), spec.np_dtype), *SCALARS,
     )
+
+
+@pytest.mark.parametrize("spec,nb", [
+    (specs.F32, FULL_F32), (specs.F32, TAIL_F32), (specs.BF16, FULL_16),
+], ids=["f32-full", "f32-tail", "bf16-full"])
+def test_encode_program_compiles_without_scatter(compile_native, spec, nb):
+    """The whole encode program (kernel and body assembly) at frame size: the
+    compacted sections are laid out by shifts and selects, never a scatter."""
+    text = compile_native(
+        lambda x, e, pe: device._encode_device_jit(x, e, pe, spec=spec, backend="kernel"),
+        ((nb, 128), spec.np_dtype), ((), spec.compute_np_dtype), ((), jnp.int32),
+    )
+    assert "scatter(" not in text
 
 
 def _body_bytes(spec, nb, bs=128):

@@ -18,6 +18,10 @@ every number is within its limit.
   got back (limit 0: the benchmark times the device route).
 * ``calls_without_device_ops`` (traced runs): the window's calls inside
   whose annotation no operation ran on the chip (limit 0).
+
+A traffic kind of its own (``perfbench/kinds/``) may add numbers with limits
+of its own (its ``LIMITS``, none of them a name above); its units are held
+to the route counters below like the built-in kinds' units.
 """
 from __future__ import annotations
 
@@ -67,9 +71,11 @@ def _compare_values(got: np.ndarray, want: np.ndarray, x: np.ndarray, e: float):
     return differ, worst / e
 
 
-def numbers(values: dict) -> dict:
-    """``{name: {"value", "limit"}}`` for every number compared."""
-    return {k: {"value": v, "limit": LIMITS[k]} for k, v in values.items()}
+def numbers(values: dict, limits: dict | None = None) -> dict:
+    """``{name: {"value", "limit"}}`` for every number compared; a number
+    that ``LIMITS`` lacks takes its limit from ``limits`` (a kind's own)."""
+    return {k: {"value": v, "limit": LIMITS[k] if k in LIMITS else limits[k]}
+            for k, v in values.items()}
 
 
 def correct(checked: dict) -> bool:

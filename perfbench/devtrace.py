@@ -7,6 +7,14 @@ planes (``/device:TPU:<n>``) carry the operations the chip ran, on the line
 runtime's own host events.  Busy time is the union of the device operations'
 intervals: time in which at least one operation ran.  All times here are in
 nanoseconds on the trace's clock, returned as seconds.
+
+An operation's scope is the ``tf_op`` stat of its event metadata: the
+``jax.named_scope`` path it was traced under
+(``jit(_encode_device_jit)/szx_assemble/compact_mid/...``).  ``ProfileData``
+does not show metadata stats, so ``op_scopes`` reads them from the serialized
+XSpace itself.  Events are named by their HLO text, which two programs can
+share under different scopes: such a name is ambiguous, and its time is
+given to no scope.
 """
 from __future__ import annotations
 
@@ -20,6 +28,96 @@ DEVICE_PREFIX = "/device:TPU:"
 OPS_LINE = "XLA Ops"
 HOST_PLANE = "/host:CPU"
 ANNOTATION = "bench."
+SCOPE_STAT = "tf_op"
+
+
+def _varint(buf, i: int) -> tuple[int, int]:
+    value = shift = 0
+    while True:
+        b = buf[i]
+        i += 1
+        value |= (b & 0x7F) << shift
+        if b < 0x80:
+            return value, i
+        shift += 7
+
+
+def _fields(buf, lo: int = 0, hi: int | None = None):
+    """``(field number, value)`` of the protobuf message in ``buf[lo:hi]``:
+    an int for a varint, a ``(start, end)`` slice of ``buf`` for a
+    length-delimited field, None for a fixed-width one."""
+    hi = len(buf) if hi is None else hi
+    i = lo
+    while i < hi:
+        key, i = _varint(buf, i)
+        wire = key & 7
+        if wire == 0:
+            value, i = _varint(buf, i)
+        elif wire == 2:
+            n, i = _varint(buf, i)
+            value, i = (i, i + n), i + n
+        elif wire in (1, 5):
+            value, i = None, i + (8 if wire == 1 else 4)
+        else:
+            raise ValueError(f"unsupported protobuf wire type {wire}")
+        yield key >> 3, value
+
+
+def _text(buf, span) -> str:
+    return bytes(buf[span[0]:span[1]]).decode("utf-8", "replace")
+
+
+def _map_values(buf, entries) -> list:
+    """The value slices of protobuf map entries (key 1, value 2)."""
+    return [v for lo, hi in entries for n, v in _fields(buf, lo, hi) if n == 2]
+
+
+def op_scopes(xspace: bytes, chips=None) -> tuple[dict, set]:
+    """``({op name: scope}, ambiguous names)`` over the device planes of
+    ``chips`` (all, if None), from a serialized XSpace (``xplane.proto``:
+    XSpace.planes 1; XPlane.name 2, event_metadata 4, stat_metadata 5;
+    XEventMetadata.name 2, stats 5; XStat.metadata_id 1, str_value 5,
+    ref_value 7; XStatMetadata.id 1, name 2).  A name seen under two
+    different scopes is ambiguous and gets none."""
+    buf = memoryview(xspace)
+    seen: dict = defaultdict(set)
+    for n, plane in _fields(buf):
+        if n != 1:
+            continue
+        name, events, stats = "", [], []
+        for m, v in _fields(buf, *plane):
+            if m == 2:
+                name = _text(buf, v)
+            elif m == 4:
+                events.append(v)
+            elif m == 5:
+                stats.append(v)
+        chip = name[len(DEVICE_PREFIX):]
+        if not (name.startswith(DEVICE_PREFIX) and chip.isdigit()):
+            continue
+        if chips is not None and int(chip) not in chips:
+            continue
+        stat_names = {}
+        for lo, hi in _map_values(buf, stats):
+            f = dict(_fields(buf, lo, hi))
+            stat_names[f.get(1, 0)] = _text(buf, f[2]) if 2 in f else ""
+        for lo, hi in _map_values(buf, events):
+            op, scope = "", None
+            for m, v in _fields(buf, lo, hi):
+                if m == 2:
+                    op = _text(buf, v)
+                elif m == 5:
+                    f = dict(_fields(buf, *v))
+                    if stat_names.get(f.get(1, 0)) != SCOPE_STAT:
+                        continue
+                    if 5 in f:
+                        scope = _text(buf, f[5])
+                    elif 7 in f:
+                        scope = stat_names.get(f[7], "")
+            if scope is not None:
+                seen[op].add(scope)
+    scopes = {op: next(iter(s)) for op, s in seen.items() if len(s) == 1}
+    return scopes, {op for op, s in seen.items() if len(s) > 1}
 
 
 def union(intervals) -> list:
@@ -74,8 +172,11 @@ class Reduction:
     """A traced window reduced to intervals: device ops per chip, the
     benchmark's annotations by kind, and the host events of their thread."""
 
-    def __init__(self, planes, chips=None):
-        """``chips``: the ids of the devices the cell runs on (all, if None)."""
+    def __init__(self, planes, chips=None, scopes=None, ambiguous=()):
+        """``chips``: the ids of the devices the cell runs on (all, if None);
+        ``scopes``, ``ambiguous``: what ``op_scopes`` read of the ops."""
+        self.scopes: dict = dict(scopes or {})   # op name -> tf_op scope
+        self.ambiguous: set = set(ambiguous)     # op names of two scopes
         self.ops: dict = {}                  # chip -> [(start, end, name)]
         self.spans: dict = defaultdict(list)  # kind -> [(start, end)]
         self.host: list = []                 # (start, end, name) on the annotated thread
@@ -112,7 +213,10 @@ class Reduction:
         paths = sorted(glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"), recursive=True))
         if not paths:
             raise FileNotFoundError(f"no .xplane.pb under {log_dir}")
-        return cls(ProfileData.from_file(paths[-1]).planes, chips)
+        with open(paths[-1], "rb") as f:
+            xspace = f.read()
+        return cls(ProfileData.from_serialized_xspace(xspace).planes, chips,
+                   *op_scopes(xspace, chips))
 
     # ----------------------------------------------------------- numbers
     def span_seconds(self, kind: str) -> float:
@@ -135,6 +239,25 @@ class Reduction:
             if not region:
                 return None
         return sum(overlap(b, region) for b in self.busy.values()) / len(self.busy) / 1e9
+
+    def scoped_seconds(self, kind: str, scope: str) -> float | None:
+        """Device time of the ops with ``scope`` among the components of
+        their scope path, inside the ``kind`` annotations, averaged over the
+        chips; None where the trace names no op's scope or holds no ``kind``
+        annotation."""
+        region = self.kind_union.get(kind)
+        if not region or not self.scopes or not self.ops:
+            return None
+        inside = {name for name, path in self.scopes.items() if scope in path.split("/")}
+        return sum(overlap(union((s, e) for s, e, name in ops if name in inside), region)
+                   for ops in self.ops.values()) / len(self.ops) / 1e9
+
+    def ambiguous_seconds(self, kind: str) -> float:
+        """Device time of the ops whose name two scopes share (given to no
+        scope), inside the ``kind`` annotations, averaged over the chips."""
+        region = self.kind_union.get(kind, [])
+        return sum(overlap(union((s, e) for s, e, name in ops if name in self.ambiguous),
+                           region) for ops in self.ops.values()) / max(len(self.ops), 1) / 1e9
 
     def silent(self, kind: str) -> int:
         """``kind`` annotations inside which no operation ran on any chip."""

@@ -6,7 +6,9 @@
 
 The cell names a configuration (``perfbench/configs/<name>.json``: the
 field's shape, dtype, bound and generator) and a traffic mix
-(``perfbench/mixes/<traffic>.json``); each metric is read by
+(``perfbench/mixes/<traffic>.json``) whose ``kind`` is built in
+(``traffic.py``) or a module of its own (``perfbench/kinds/<kind>.py``, see
+``perfbench/kinds/README.md``); each metric is read by
 ``perfbench/metrics/<metric>.py``.  A run makes the field from ``--seed``,
 warms every program the window will use, measures for ``--seconds``, and then
 checks what the timed path produced against the plain reference
@@ -116,9 +118,9 @@ def load_reader(name: str):
     return mod
 
 
-def chip(chips: int, dtype: str) -> list:
+def chip(chips: int, *dtypes: str) -> list:
     """The devices a cell runs on; NoChip where they are not TPUs enough, or
-    where the codec's ``auto`` route for ``dtype`` is not the kernels."""
+    where the codec's ``auto`` route for any of ``dtypes`` is not the kernels."""
     import jax
 
     from repro.kernels import ops, specs
@@ -128,10 +130,11 @@ def chip(chips: int, dtype: str) -> list:
         raise NoChip(f"no TPU: JAX found {devs[0].platform!r} devices")
     if len(devs) < chips:
         raise NoChip(f"the cell needs {chips} chips, JAX found {len(devs)}")
-    route = ops._resolve("auto", next(s for s in specs.SPECS if s.name == dtype))
-    if route != "kernel":
-        raise NoChip(f"backend 'auto' resolves {dtype} to {route!r}, not 'kernel' "
-                     "(is SZX_OPS_BACKEND set?)")
+    for dtype in dtypes:
+        route = ops._resolve("auto", next(s for s in specs.SPECS if s.name == dtype))
+        if route != "kernel":
+            raise NoChip(f"backend 'auto' resolves {dtype} to {route!r}, not 'kernel' "
+                         "(is SZX_OPS_BACKEND set?)")
     return devs[:chips]
 
 
@@ -162,7 +165,8 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float, trace: bool,
         config = json.loads((ROOT / entry["file"]).read_text())
     if mix is None:
         mix = json.loads((HERE / "mixes" / f"{cell['traffic']}.json").read_text())
-    devs = devices if devices is not None else chip(cell["chips"], config["dtype"])
+    dtypes = config["dtypes"] if "dtypes" in config else [config["dtype"]]
+    devs = devices if devices is not None else chip(cell["chips"], *dtypes)
     peak = peak if peak is not None else peak_of(devs[0].device_kind)
     compiles = Compiles()
     from repro import obs
@@ -170,8 +174,9 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float, trace: bool,
     obs_was_on = obs.enabled()
     obs.enable()                         # the transfer counters show the route taken
     obs.reset()
-    x = datagen.make_field(config, seed)
-    gen = traffic.make(config, mix, x, seed)
+    kind = None if mix["kind"] in traffic.KINDS else traffic.load_kind(mix["kind"])
+    x = getattr(kind, "make_input", datagen.make_field)(config, seed)
+    gen = traffic.make(config, mix, x, seed, devs, kind)
     log_dir = tempfile.mkdtemp(prefix="perfbench-trace-") if trace else None
     try:
         off_route = check.host_path_frames(gen.warm())
@@ -199,7 +204,7 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float, trace: bool,
             if not reduction.busy or not any(reduction.busy.values()):
                 raise RuntimeError("the trace holds no operation on the cell's devices "
                                    f"({sorted(d.id for d in devs)}): nothing to reduce")
-        checked = gen.check(window)
+        checked = gen.check(window, reduction)
         checked.update(check.numbers({"host_path_frames": off_route}))
         if trace:
             checked.update(check.numbers({"calls_without_device_ops":
@@ -238,6 +243,12 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float, trace: bool,
                             for n, v in c.items()} for k, c in checked.items()}
     print(f"window: {window.t_end - window.t_start:.3f} s, programs lowered in it: "
           f"{compiles.lowered}, compiled in it: {compiles.compiled}", file=sys.stderr)
+    if reduction is not None:
+        print(f"trace: {len(reduction.scopes)} op names with a scope, "
+              f"{len(reduction.ambiguous)} of two scopes "
+              f"({sum(map(reduction.ambiguous_seconds, kinds)):.6f} s a chip "
+              "inside the calls, given to no scope)",
+              file=sys.stderr)
     return result
 
 

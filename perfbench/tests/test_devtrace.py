@@ -144,3 +144,97 @@ def test_silent_annotations_and_chip_filter(red):
     assert reduce(TRACE, {0}).busy_seconds("compress") == pytest.approx(0.008)
     other = reduce(TRACE, {1})
     assert other.busy == {} and other.busy_seconds() is None
+
+
+# The same trace with each device op's scope (the ``tf_op`` stat of its event
+# metadata, once as a string and once by reference), a decode gather op in
+# the restore [13, 16] ms, and a second chip whose ``scatter.7`` has another
+# scope.
+SCOPED_OPS = """
+    events { metadata_id: 4 offset_ps: %d duration_ps: %d }""" % (13 * MS, 3 * MS)
+SCOPED_META = """
+  event_metadata { key: 1 value { id: 1 name: "szx_encode_float32"
+    stats { metadata_id: 10 str_value: "jit(_encode_device_jit)/szx_encode" } } }
+  event_metadata { key: 2 value { id: 2 name: "scatter.7"
+    stats { metadata_id: 10 ref_value: 11 } } }
+  event_metadata { key: 4 value { id: 4 name: "gather.3"
+    stats { metadata_id: 10 str_value: "jit(_decode_device_jit)/szx_gather/gather" } } }
+  stat_metadata { key: 10 value { id: 10 name: "tf_op" } }
+  stat_metadata { key: 11 value { id: 11 name: "jit(_encode_device_jit)/szx_assemble/compact_mid/scatter" } }
+"""
+OTHER_CHIP = f"""
+planes {{
+  id: 3 name: "/device:TPU:1"
+  lines {{
+    id: 1 name: "XLA Ops" timestamp_ns: 0
+    events {{ metadata_id: 2 offset_ps: {2 * MS} duration_ps: {2 * MS} }}
+  }}
+  event_metadata {{ key: 2 value {{ id: 2 name: "scatter.7"
+    stats {{ metadata_id: 10 str_value: "jit(other)/elsewhere" }} }} }}
+  stat_metadata {{ key: 10 value {{ id: 10 name: "tf_op" }} }}
+}}
+"""
+
+
+def scoped_trace(chips_text: str = "") -> str:
+    last_op = f"events {{ metadata_id: 2 offset_ps: {10 * MS} duration_ps: {2 * MS} }}"
+    meta = 'event_metadata { key: 3 value { id: 3 name: "jit__encode_device_jit" } }'
+    assert last_op in TRACE and meta in TRACE
+    return (TRACE.replace(last_op, last_op + SCOPED_OPS).replace(meta, meta + SCOPED_META)
+            + chips_text)
+
+
+def from_dir(tmp_path, text, chips=None):
+    (tmp_path / "run").mkdir(exist_ok=True)
+    (tmp_path / "run" / "host.xplane.pb").write_bytes(
+        ProfileData.text_proto_to_serialized_xspace(text))
+    return devtrace.Reduction.from_dir(str(tmp_path), chips)
+
+
+def test_op_scopes_from_serialized_xspace():
+    xspace = ProfileData.text_proto_to_serialized_xspace(scoped_trace())
+    scopes, ambiguous = devtrace.op_scopes(xspace)
+    assert scopes == {"szx_encode_float32": "jit(_encode_device_jit)/szx_encode",
+                      "scatter.7": "jit(_encode_device_jit)/szx_assemble/compact_mid/scatter",
+                      "gather.3": "jit(_decode_device_jit)/szx_gather/gather"}
+    assert ambiguous == set()
+    assert devtrace.op_scopes(ProfileData.text_proto_to_serialized_xspace(TRACE)) == ({}, set())
+
+
+def test_a_name_of_two_scopes_is_ambiguous(tmp_path):
+    both = from_dir(tmp_path, scoped_trace(OTHER_CHIP))
+    assert both.ambiguous == {"scatter.7"} and "scatter.7" not in both.scopes
+    # chip 0 ran scatter.7 [3, 8] and chip 1 [2, 4] inside the compress: 7 ms over 2 chips
+    assert both.ambiguous_seconds("compress") == pytest.approx(0.0035)
+    assert both.scoped_seconds("compress", "szx_assemble") == 0
+    one = from_dir(tmp_path, scoped_trace(OTHER_CHIP), chips={0})
+    assert one.ambiguous == set() and one.ambiguous_seconds("compress") == 0
+    assert one.scoped_seconds("compress", "szx_assemble") == pytest.approx(0.005)
+
+
+SCOPED_UNITS = [traffic.Unit("compress", 0, 0.009, 6_000, 2_000, frames=2),
+                traffic.Unit("decompress", 0.009, 0.02, 6_000, 2_000, frames=2)]
+
+
+def test_program_section_readers(tmp_path):
+    c = ctx(from_dir(tmp_path, scoped_trace()), SCOPED_UNITS)
+    # szx_assemble: scatter.7 [3, 8] inside the compress [0, 9], not its
+    # [10, 12] in the restore; szx_gather: gather.3 [13, 16]; two frames each
+    assert run.load_reader("encode_program.assemble_ms_per_frame").read(c) == pytest.approx(2.5)
+    assert run.load_reader("decode_program.gather_ms_per_frame").read(c) == pytest.approx(1.5)
+    assert c.trace.scoped_seconds("compress", "compact_mid") == pytest.approx(0.005)
+    assert c.trace.scoped_seconds("compress", "compact") == 0      # a whole component only
+    assert c.trace.scoped_seconds("roi", "szx_gather") is None
+
+
+@pytest.mark.parametrize("name", ["encode_program.assemble_ms_per_frame",
+                                  "decode_program.gather_ms_per_frame"])
+def test_program_section_readers_silent(tmp_path, red, name):
+    """Without a trace, without scopes (a trace that names none), or with
+    the scope's ops all of ambiguous name, a reader returns nothing."""
+    reader = run.load_reader(name)
+    assert reader.read(ctx(None, SCOPED_UNITS)) is None
+    assert reader.read(ctx(red, SCOPED_UNITS)) is None
+    both = from_dir(tmp_path, scoped_trace(OTHER_CHIP))
+    if name.startswith("encode"):
+        assert reader.read(ctx(both, SCOPED_UNITS)) is None

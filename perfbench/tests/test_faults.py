@@ -19,13 +19,14 @@ SMALL = {"hurricane-isabel": {"shape": [10, 50, 50], "frame_bytes": 64 << 10},
          "nyx": {"shape": [32, 32, 32], "frame_bytes": 32 << 10}}
 
 
-def small_run(workload: str, seed: int = 2**31 + 11) -> dict:
+def small_run(workload: str, seed: int = 2**31 + 11, **mix_keys) -> dict:
     cell = next(w for w in BENCH["workloads"] if w["name"] == workload)
     entry = next(c for c in BENCH["configs"] if c["name"] == cell["config"])
     config = dict(json.loads((ROOT / entry["file"]).read_text()), **SMALL[cell["config"]])
     mix = json.loads((ROOT / "perfbench" / "mixes" / f"{cell['traffic']}.json").read_text())
     if mix["kind"] == "roi":
         mix["chunk_bytes"] = 20_000          # several chunks of 2 levels
+    mix.update(mix_keys)
     return run.run_cell(BENCH, workload, seed, 0.5, False, config=config, mix=mix,
                         devices=jax.devices()[:1], peak={"hbm_bytes_per_s": 819e9})
 

@@ -18,14 +18,19 @@ transfers) is timed in full.  The window is cut between units: a unit (for
 ``roundtrip``, a compress and the restore of its frames) started before the
 deadline finishes and counts.  Every unit runs inside a
 ``jax.profiler.TraceAnnotation`` named ``bench.<kind>``.
+
+Any other ``kind`` is a module of its own, ``perfbench/kinds/<kind>.py``,
+loaded by ``load_kind``; ``perfbench/kinds/README.md`` gives its contract.
 """
 from __future__ import annotations
 
+import importlib.util
 import os
 import tempfile
 import time
 import traceback
 from dataclasses import dataclass, field as dfield
+from pathlib import Path
 
 import numpy as np
 
@@ -33,11 +38,12 @@ import check
 import reference
 
 KEEP_BYTES = 1 << 30          # restored fields kept for the check, at most
+KINDS_DIR = Path(__file__).resolve().parent / "kinds"
 
 
 @dataclass
 class Unit:
-    kind: str                 # compress | decompress | roi
+    kind: str                 # compress | decompress | roi, or a kind module's own
     t0: float
     t1: float
     raw_bytes: int
@@ -167,7 +173,7 @@ class Roundtrip:
                             sum(len(f) for f in frames), len(frames)))
         w.keep(self.rng, self.cap, len(w.legs) - 1, y)
 
-    def check(self, w: Window) -> dict:
+    def check(self, w: Window, reduction=None) -> dict:
         return check.roundtrip(self.x, self.config, w.legs, w.outputs, self.frame_bytes)
 
     def close(self) -> None:
@@ -250,7 +256,7 @@ class RegionReads:
         on_close()
         return w
 
-    def check(self, w: Window) -> dict:
+    def check(self, w: Window, reduction=None) -> dict:
         return check.region_reads(self.x, self.config, self.chunk_shape, self.axis,
                                   self.width, w.outputs)
 
@@ -261,7 +267,28 @@ class RegionReads:
 KINDS = {"roundtrip": Roundtrip, "roi": RegionReads}
 
 
-def make(config: dict, mix: dict, x: np.ndarray, seed: int):
+def load_kind(name: str):
+    """The module ``KINDS_DIR/<name>.py`` of a kind that is not built in.
+    Its ``LIMITS`` may only add numbers: a name that ``check.LIMITS`` has
+    already is refused, so a kind can never loosen a limit."""
+    path = KINDS_DIR / f"{name}.py"
+    if not path.is_file():
+        raise ValueError(f"unknown traffic kind {name!r}: no {path}")
+    spec = importlib.util.spec_from_file_location(f"perfbench_kind_{name}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    clash = sorted(set(getattr(mod, "LIMITS", {})) & set(check.LIMITS))
+    if clash:
+        raise ValueError(f"kind {name!r} redefines the limits of {clash}")
+    return mod
+
+
+def make(config: dict, mix: dict, x, seed: int, devices: list, module):
+    """The generator of ``mix``'s kind: the built-in one where ``module`` is
+    None, else ``module.Kind`` (``load_kind``), which also gets the cell's
+    devices."""
     if mix.get("callers", 1) != 1:
         raise ValueError("the generator drives one caller in a closed loop")
-    return KINDS[mix["kind"]](config, mix, x, seed)
+    if module is None:
+        return KINDS[mix["kind"]](config, mix, x, seed)
+    return module.Kind(config, mix, x, seed, list(devices))
